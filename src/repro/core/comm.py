@@ -46,11 +46,7 @@ class AxisComm:
         self.axis_name = axis_name
 
     def axis_size(self) -> int:
-        if hasattr(jax.lax, "axis_size"):
-            return jax.lax.axis_size(self.axis_name)
-        # legacy jax: psum of a Python 1 over a named axis constant-folds
-        # to the axis size as a Python int
-        return jax.lax.psum(1, self.axis_name)
+        return jax.lax.axis_size(self.axis_name)
 
     def axis_index(self):
         return jax.lax.axis_index(self.axis_name)

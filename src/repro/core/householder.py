@@ -18,10 +18,11 @@ the kernels cover. Note the 2-D test sees *per-call* rank: under ``vmap``
 (SimComm's ``map_local``) per-lane tracers are 2-D, so vmapped call sites
 dispatch too and batch through ``pallas_call``'s batching rule (exercised
 by the forced-kernel SimComm sweep test). Explicitly batched arrays with a
-leading lane axis (e.g. the SimComm trailing ``_combine``), other dtypes,
-and explicit ``num_cols`` take the pure-jnp implementations below, which
-are also the oracles the kernels are validated against (``ref.py`` binds
-the ``_``-prefixed pure forms directly, never the dispatchers).
+leading lane axis, other dtypes, and explicit ``num_cols`` take the
+pure-jnp implementations below, which are also the oracles the kernels are
+validated against (``ref.py`` binds the ``_``-prefixed pure forms
+directly, never the dispatchers); the SimComm trailing ``_combine``
+vmaps its lane-stacked arrays into this seam where the dispatch is on.
 """
 from __future__ import annotations
 
@@ -30,6 +31,17 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+
+# f32 means f32: every matmul of the sweep (here, in the rest of
+# ``repro.core`` and in the kernels' tile math) runs at HIGHEST precision.
+# A TPU otherwise lowers an f32 dot to a single bf16 pass, good to ~1e-3
+# relative; CPU backends ignore the setting. DESIGN.md §10.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def mm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at the sweep's matmul precision."""
+    return jnp.matmul(a, b, precision=MATMUL_PRECISION)
 
 
 def _kernel_dispatch(*arrays) -> bool:
@@ -123,7 +135,7 @@ def _householder_qr_masked(
         # zeros at and below the pivot in the masked region only where v acts,
         # and v^T A on them is ~0, so the full-width update is exact and keeps
         # the loop free of dynamic slices.
-        w = v @ A_  # (n,)
+        w = mm(v, A_)  # (n,)
         A_ = A_ - tau * jnp.outer(v, w)
         Y_ = Y_.at[:, j].set(v)
         taus_ = taus_.at[j].set(tau)
@@ -180,12 +192,12 @@ def build_t(Y: jax.Array, taus: jax.Array) -> jax.Array:
     static-shaped.
     """
     n = Y.shape[1]
-    G = Y.T @ Y  # (n, n)
+    G = mm(Y.T, Y)  # (n, n)
     idx = jnp.arange(n)
 
     def body(j, T):
         g = jnp.where(idx < j, G[:, j], 0.0)  # (n,)
-        col = -taus[j] * (T @ g)
+        col = -taus[j] * mm(T, g)
         col = jnp.where(idx < j, col, 0.0)
         col = col.at[j].set(taus[j])
         return T.at[:, j].set(col)
@@ -205,22 +217,22 @@ def apply_qt(Y: jax.Array, T: jax.Array, C: jax.Array) -> jax.Array:
 
 @jax.jit
 def _apply_qt(Y: jax.Array, T: jax.Array, C: jax.Array) -> jax.Array:
-    W = T.T @ (Y.T @ C)
-    return C - Y @ W
+    W = mm(T.T, mm(Y.T, C))
+    return C - mm(Y, W)
 
 
 @jax.jit
 def apply_q(Y: jax.Array, T: jax.Array, C: jax.Array) -> jax.Array:
     """Q C = C - Y (T (Y^T C))."""
-    W = T @ (Y.T @ C)
-    return C - Y @ W
+    W = mm(T, mm(Y.T, C))
+    return C - mm(Y, W)
 
 
 @jax.jit
 def q_dense(Y: jax.Array, T: jax.Array) -> jax.Array:
     """Materialize Q = I - Y T Y^T (testing / small sizes only)."""
     m = Y.shape[0]
-    return jnp.eye(m, dtype=Y.dtype) - Y @ (T @ Y.T)
+    return jnp.eye(m, dtype=Y.dtype) - mm(Y, mm(T, Y.T))
 
 
 class StackedQR(NamedTuple):
@@ -285,8 +297,8 @@ def stacked_apply_qt(
 def _stacked_apply_qt(
     sq: StackedQR, C_top: jax.Array, C_bot: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    W = sq.T.T @ (C_top + sq.Y2.T @ C_bot)
-    return C_top - W, C_bot - sq.Y2 @ W, W
+    W = mm(sq.T.T, C_top + mm(sq.Y2.T, C_bot))
+    return C_top - W, C_bot - mm(sq.Y2, W), W
 
 
 @jax.jit
@@ -294,5 +306,5 @@ def stacked_apply_q(
     sq: StackedQR, C_top: jax.Array, C_bot: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Apply the stacked Q (not transposed) to [C_top; C_bot]."""
-    W = sq.T @ (C_top + sq.Y2.T @ C_bot)
-    return C_top - W, C_bot - sq.Y2 @ W
+    W = mm(sq.T, C_top + mm(sq.Y2.T, C_bot))
+    return C_top - W, C_bot - mm(sq.Y2, W)

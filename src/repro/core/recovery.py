@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import SimComm
-from repro.core.householder import apply_qt, householder_qr_masked
+from repro.core.householder import apply_qt, householder_qr_masked, mm
 from repro.core.trailing import _combine
 from repro.core.tsqr import DistTSQRFactors, _levels, _xor_perm, ft_tsqr
 
@@ -120,7 +120,7 @@ def recover_cprime(
     failed_was_top = bundle.buddy_was_top[source]
     Y2 = bundle.Y2[source]
     top_update = C_failed - W
-    bot_update = C_failed - Y2 @ W
+    bot_update = C_failed - mm(Y2, W)
     return jnp.where(failed_was_top, top_update, bot_update)
 
 

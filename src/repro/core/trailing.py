@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.comm import SimComm
-from repro.core.householder import apply_qt
+from repro.core.householder import apply_qt, mm
 from repro.core.tsqr import DistTSQRFactors, _levels, _xor_perm
 
 
@@ -59,17 +59,26 @@ class RecoveryBundle(NamedTuple):
 
 
 def _combine(Y2, T, C_top, C_bot):
-    """Paper's W-form combine (batched under SimComm via .mT / matmul).
+    """Paper's W-form combine. Per-lane (2-D) calls go through the
+    kernel-dispatch seam (``stacked_apply_qt``): the fused trailing-combine
+    kernel where the policy dispatches, the pure-jnp form elsewhere.
 
-    Unbatched f32 calls (the AxisComm/shard_map production path) dispatch to
-    the fused trailing-combine Pallas kernel via ``stacked_apply_qt``.
+    Lane-stacked SimComm arrays run the batched jnp form — unless the
+    kernel dispatch is on (TPU), where they are vmapped through the
+    per-lane seam instead: the REBUILD replay (``core.recovery``) combines
+    per lane under ``map_local``, and a replayed combine must be the same
+    floating-point program as the one it replaces.
     """
     if Y2.ndim == 2:
         from repro.core.householder import StackedQR, stacked_apply_qt
 
         return stacked_apply_qt(StackedQR(Y2=Y2, T=T, R=T), C_top, C_bot)
-    W = T.mT @ (C_top + Y2.mT @ C_bot)
-    return C_top - W, C_bot - Y2 @ W, W
+    from repro.kernels import backend
+
+    if backend.dispatch_enabled():
+        return jax.vmap(_combine)(Y2, T, C_top, C_bot)
+    W = mm(T.mT, C_top + mm(Y2.mT, C_bot))
+    return C_top - W, C_bot - mm(Y2, W), W
 
 
 class TrailingLevelStep(NamedTuple):
@@ -327,7 +336,7 @@ def trailing_update_baseline(
         # apply its own "Y_0" to W, but the stacked Y2 is not computable from
         # the sender's R alone — shipping V resolves this; adaptation noted
         # in DESIGN.md).
-        V = Y2 @ W
+        V = mm(Y2, W)
         down = [(i - stride, i) for i in range(P) if i % group == stride]
         V_from_even = comm.ppermute(V, down)
         is_odd = (idx % group) == stride

@@ -1,81 +1,40 @@
-"""Version-compat shims for the jax sharding / SPMD API surface.
-
-The SPMD entrypoints target the modern spelling — ``jax.make_mesh(...,
-axis_types=...)``, ``jax.set_mesh``, ``jax.shard_map(..., check_vma=...)``
-— but the pinned image may carry an older jax (0.4.x) where those live
-under different names (``jax.experimental.shard_map.shard_map`` with
-``check_rep``/``auto``, the ``Mesh`` object itself as the ambient-mesh
-context manager) or do not exist (``jax.sharding.AxisType``). Everything
-that builds meshes or shard_maps goes through this module so the rest of
-the tree is version-agnostic; the subprocess SPMD tests
-(``tests/test_spmd_subprocess.py``, ``tests/test_spmd_ft_driver.py``) run
-against exactly these shims.
-
-No behavior differences are papered over: on every supported version a mesh
-axis is *manual* inside the mapped body unless listed in the modern API's
-``axis_names`` (translated to the legacy ``auto`` complement), and
-replication checking is off by default, matching the repo's explicit-spec
-style.
+"""The jax sharding / SPMD spellings every mesh and shard_map in the tree
+goes through (jax 0.9): ``jax.make_mesh`` with explicit Auto axis types,
+``jax.set_mesh`` as the ambient-mesh context, and ``jax.shard_map`` with
+replication checking off by default, matching the repo's explicit-spec
+style. A mesh axis is *manual* inside the mapped body unless left out of
+``axis_names``.
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Optional, Sequence, Set
 
 import jax
 
-# ``jax.sharding.AxisType`` appeared well after 0.4.x; its absence is the
-# marker for the whole legacy surface.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-HAS_MODERN_SHARDING = _AXIS_TYPE is not None
-
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    if HAS_MODERN_SHARDING:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(_AXIS_TYPE.Auto,) * len(tuple(axis_names)),
-            devices=devices,
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                         devices=devices)
+    """``jax.make_mesh`` with explicit-Auto axis types."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        devices=devices,
+    )
 
 
-@contextlib.contextmanager
 def set_mesh(mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` where it exists, else the
-    ``Mesh`` object's own context manager (which binds the 0.4.x resource
-    env that ``with_sharding_constraint(x, PartitionSpec)`` reads)."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield
-    else:
-        with mesh:
-            yield
+    """Ambient-mesh context (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh, in_specs, out_specs, *, check: bool = False,
               axis_names: Optional[Set[str]] = None):
-    """``jax.shard_map`` / legacy ``jax.experimental.shard_map.shard_map``.
-
-    ``check`` maps to ``check_vma`` (modern) / ``check_rep`` (legacy).
-    ``axis_names`` is the modern "manual axes" set; axes outside it stay
-    automatic (XLA-sharded inside the body). On legacy jax the partial-auto
-    translation (``auto =`` the complement) trips an XLA partitioner check
-    (``IsManualSubgroup`` failure), so there we degrade to fully-manual:
-    unmentioned axes replicate instead of auto-sharding — identical results
-    for bodies that only use collectives on the manual axes (ours), less
-    intra-body parallelism on the old runtime.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check)
+    """``jax.shard_map``; ``check`` maps to ``check_vma``. ``axis_names``
+    is the set of manual axes; axes outside it stay automatic
+    (XLA-sharded inside the body)."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)

@@ -68,12 +68,13 @@ def _sentinel_values(comm, state: SweepState) -> np.ndarray:
     """One float per lane: the sum of this lane's sentinel slots (NaN iff
     any probe slot is NaN). Probes the block-row head plus whatever
     in-flight per-lane artifact heads exist at the current cursor."""
-    P = comm.axis_size()
     probes = []
     for field in ("A", "window", "R_leaf", "R_carry", "C_prime"):
         x = getattr(state, field)
         if x is not None:
-            probes.append(x.reshape(P, -1)[:, 0])
+            # the head element of each lane's slice, read in place (a
+            # flattening reshape would copy the whole array on TPU)
+            probes.append(x[(slice(None),) + (0,) * (x.ndim - 1)])
     return np.asarray(jnp.sum(jnp.stack(probes), axis=0))
 
 

@@ -41,6 +41,7 @@ masking runs through the SimComm primitives on the identical global layout.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -83,6 +84,40 @@ FaultHook = Callable[[object, SweepState], SweepState]
 BoundaryHook = Callable[["SweepOrchestrator"], None]
 
 
+def forwarding_jit(f: Callable):
+    """``jax.jit(f)`` for a function of one pytree whose unchanged leaves
+    come back as the caller's own arrays.
+
+    A jitted program materializes every output, so a sweep segment would
+    copy every leaf it passes through — the source matrix, every stored
+    panel's factors and bundles — and hold input and output state at once
+    (twice the state in device memory). Here the traced function returns
+    only the leaves it computed; the leaves it returns untouched (the same
+    tracer object as an input leaf) are re-inserted on the host. The map
+    from output to input is recorded while tracing, once per input
+    structure. ``_cache_size()`` counts the compiled specializations."""
+    plans: Dict = {}
+
+    @functools.partial(jax.jit, static_argnums=1)
+    def run(leaves, treedef):
+        out, out_tree = jax.tree_util.tree_flatten(
+            f(jax.tree_util.tree_unflatten(treedef, leaves)))
+        index = {id(x): i for i, x in enumerate(leaves)}
+        src = tuple(index.get(id(x)) for x in out)
+        plans[treedef] = (out_tree, src)
+        return [x for x, i in zip(out, src) if i is None]
+
+    def call(tree):
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        fresh = iter(run(leaves, treedef))
+        out_tree, src = plans[treedef]
+        return jax.tree_util.tree_unflatten(
+            out_tree, [next(fresh) if i is None else leaves[i] for i in src])
+
+    call._cache_size = run._cache_size
+    return call
+
+
 def compiled_segment(comm, n_points: int) -> Callable[[SweepState], SweepState]:
     """The RESIDENT compiled segment runner: a process-wide jitted
     ``run_steps(comm, state, n_points)`` shared by every caller over the
@@ -96,7 +131,21 @@ def compiled_segment(comm, n_points: int) -> Callable[[SweepState], SweepState]:
     key = (type(comm).__name__, comm.axis_size(), n_points)
     fn = _SEGMENT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(lambda s: run_steps(comm, s, n_points))
+        fn = forwarding_jit(lambda s: run_steps(comm, s, n_points))
+        _SEGMENT_CACHE[key] = fn
+    return fn
+
+
+def compiled_finalize(comm) -> Callable[[SweepState], Tuple]:
+    """``finalize`` as one compiled program per ``(comm kind, P)`` (jax
+    specializes it per geometry): the last deposit, the stacking of every
+    panel's factors and bundles and the R assembly run without eager
+    intermediates — at the production shape the stacked outputs alone are
+    several GiB."""
+    key = (type(comm).__name__, comm.axis_size(), "finalize")
+    fn = _SEGMENT_CACHE.get(key)
+    if fn is None:
+        fn = jax.jit(lambda s: finalize(comm, s))
         _SEGMENT_CACHE[key] = fn
     return fn
 
@@ -309,7 +358,7 @@ class SweepOrchestrator:
         fn = _SEGMENT_CACHE.get(key)
         if fn is None:
             comm = self.comm
-            fn = jax.jit(lambda s: run_panel_fused(comm, s))
+            fn = forwarding_jit(lambda s: run_panel_fused(comm, s))
             _SEGMENT_CACHE[key] = fn
         return fn(state)
 
@@ -323,6 +372,11 @@ class SweepOrchestrator:
         if self.fused:
             return self._fused_segment(state)
         return self._stepped(state, self.segment_points)
+
+    def _finalize(self):
+        if not self.jit_segments:
+            return finalize(self.comm, self.state)
+        return compiled_finalize(self.comm)(self.state)
 
     # -- the host loop -----------------------------------------------------
 
@@ -381,7 +435,7 @@ class SweepOrchestrator:
                 break
         if self.elastic is not None:
             return self.elastic.finish(self.comm, self.state, self.events)
-        R, factors, bundles = finalize(self.comm, self.state)
+        R, factors, bundles = self._finalize()
         return FTSweepResult(R=R, factors=factors, bundles=bundles,
                              events=self.events)
 
@@ -479,7 +533,7 @@ class SweepOrchestrator:
                 # dispatch is stale — re-dispatch from the real boundary
                 cur = self._segment(self.state)
                 self.segments_run += 1
-        R, factors, bundles = finalize(self.comm, self.state)
+        R, factors, bundles = self._finalize()
         return FTSweepResult(R=R, factors=factors, bundles=bundles,
                              events=self.events)
 

@@ -7,7 +7,7 @@ fused_sweep - whole-panel sweep megakernel + fused leaf (panel QR + apply)
 
 ops.py is the dispatch seam ``repro.core`` routes through: wrappers that
 resolve the per-op execution policy (compiled pallas / compiled xla /
-interpret / oracle — backend.py probes what this backend can lower), pad
+interpret / oracle — backend.py holds the static engine policy), pad
 up to the pallas engines' alignment contract, consult the autotune.py
 block-shape cache, and fall back to the pure-jnp oracles in ref.py.
 See DESIGN.md §2 and §10.

@@ -2,34 +2,41 @@
 
 Three independent decisions live here:
 
-* ``kernel_mode(op)`` — HOW an op in ``repro.kernels.ops`` executes. A
-  capability-probed three-way policy per op::
+* ``kernel_mode(op)`` — HOW an op in ``repro.kernels.ops`` executes::
 
-      compiled   the fast path. Engine ``pallas`` (a native, non-interpret
-                 ``pallas_call``) on any backend that lowers it — probed
-                 once per process per op by AOT-compiling a tiny instance —
-                 with automatic fallback to engine ``xla`` (the same tile
-                 program executed as plain compiled XLA, no interpreter
-                 machinery) where lowering fails.
+      compiled   the fast path, on the engine of the static policy below.
       interpret  the Pallas interpreter (traced-Python-over-VMEM-blocks).
-                 Slow; the validation vehicle for the kernel programs and
-                 the bit-compatibility gates. Never chosen automatically —
-                 request it explicitly (tests, parity matrices).
+                 Slow; the validation vehicle for the kernel programs on
+                 CPU. Never chosen automatically — request it explicitly
+                 (tests, parity matrices).
       oracle     the pure-jnp reference in ``repro.kernels.ref``.
 
-* ``compiled_engine(op)`` — which compiled engine ``compiled`` resolves to:
-  ``pallas`` iff the per-op probe succeeded on this backend, else ``xla``.
+* ``compiled_engine(op)`` — which engine ``compiled`` runs, a written,
+  static policy (no probe, no fallback):
+
+      on TPU     ``pallas`` (native Mosaic ``pallas_call``) for every op in
+                 ``PALLAS_ON_TPU``: the four tile kernels. ``fused_sweep``
+                 runs ``xla`` — the whole-panel megakernel keeps its whole
+                 (m, w) window resident in VMEM, 128 MiB per lane at the
+                 production leaf (8192 x 4096 f32), which no TPU core holds;
+                 compiled XLA over the same core math keeps one dispatch
+                 per panel and the tile kernels inside it stay Pallas.
+      elsewhere  ``xla`` for every op (the tile program as plain compiled
+                 XLA; the Pallas TPU kernels do not lower on CPU).
+
+  A pallas kernel that fails to lower raises the compiler's error where the
+  program that calls it is compiled; nothing reroutes it.
 
 * ``dispatch_enabled()`` — WHETHER the core hot path (``repro.core``) routes
   its panel/combine/apply operations through ``ops`` at all. Default: only
   on TPU, where the fused kernels beat XLA's op-by-op lowering. The ops
-  layer itself runs its best compiled engine on every backend.
+  layer itself runs its compiled engine on every backend.
 
 Overrides, strongest first:
   1. ``use_kernels(True/False)`` — programmatic (tests, benchmarks);
      ``use_kernels(None)`` restores the automatic policy. True forces the
-     core dispatch on AND pins ops to its best kernel mode; False pins
-     everything to the oracle.
+     core dispatch on AND pins ops to compiled mode; False pins everything
+     to the oracle.
   2. ``force_mode(mode, op=None)`` — programmatic per-op (or global) mode
      pin; ``force_mode(None)`` clears.
   3. ``REPRO_NO_KERNELS=1``    — kill switch, wins over the backend default.
@@ -109,7 +116,7 @@ def kernel_mode(op: str) -> str:
     """Resolve the execution mode for ``op``: compiled | interpret | oracle.
 
     Read at trace time by ``repro.kernels.ops``. ``auto`` (the default)
-    resolves to ``compiled`` — the engine probe decides pallas vs xla.
+    resolves to ``compiled`` — ``compiled_engine`` decides pallas vs xla.
     """
     assert op in OPS, op
     if _OVERRIDE is False:
@@ -126,92 +133,41 @@ def kernel_mode(op: str) -> str:
     return mode
 
 
-# -- compiled-capability probe (once per process per op) ---------------------
+# -- the static engine policy -------------------------------------------------
 
-_PROBE_CACHE: Dict[str, bool] = {}
-_PROBE_ERRORS: Dict[str, str] = {}
-
-
-def _probe_compiled(op: str) -> bool:
-    """AOT-lower + compile a tiny aligned instance of ``op``'s Pallas kernel
-    with ``interpret=False`` on the default backend. No execution — safe to
-    call from inside an active trace (it opens its own)."""
-    import jax.numpy as jnp
-
-    f32 = jnp.float32
-    s = jax.ShapeDtypeStruct
-    try:
-        if op == "panel_qr":
-            from repro.kernels import panel_qr as m
-            fn = lambda a, rs: m.panel_qr(a, rs, interpret=False)
-            args = (s((136, 128), f32), s((), jnp.int32))
-        elif op == "stacked_qr":
-            from repro.kernels import stacked_qr as m
-            fn = lambda a, b_: m.stacked_qr(a, b_, interpret=False)
-            args = (s((128, 128), f32), s((128, 128), f32))
-        elif op == "wy_apply":
-            from repro.kernels import wy_apply as m
-            fn = lambda y, t, c: m.wy_apply(y, t, c, block_n=128,
-                                            interpret=False)
-            args = (s((128, 128), f32), s((128, 128), f32), s((128, 128), f32))
-        elif op == "stacked_apply":
-            from repro.kernels import stacked_qr as m
-            fn = lambda y2, t, ct, cb: m.stacked_apply(
-                y2, t, ct, cb, block_n=128, interpret=False)
-            args = (s((128, 128), f32),) * 4
-        elif op == "fused_sweep":
-            from repro.kernels import fused_sweep as m
-            fn = lambda w: m.panel_qr_apply(w, 0, 8, interpret=False)
-            args = (s((16, 16), f32),)
-        else:  # pragma: no cover - OPS is closed
-            return False
-        jax.jit(fn).lower(*args).compile()
-        return True
-    except Exception as e:  # noqa: BLE001 - any lowering failure => no pallas
-        _PROBE_ERRORS[op] = f"{type(e).__name__}: {e}"
-        return False
+# Ops whose compiled engine on TPU is the native Pallas kernel (see the module
+# docstring for why ``fused_sweep`` is not among them).
+PALLAS_ON_TPU = ("panel_qr", "stacked_qr", "wy_apply", "stacked_apply")
 
 
-def compiled_supported(op: str) -> bool:
-    """Does this backend lower ``op``'s Pallas kernel natively? Probed once
-    per process; ``probe_report()`` has the failure reasons."""
-    if op not in _PROBE_CACHE:
-        _PROBE_CACHE[op] = _probe_compiled(op)
-    return _PROBE_CACHE[op]
+def platform() -> str:
+    """The default backend's platform (``tpu``, ``cpu``, ...)."""
+    return jax.default_backend()
 
 
 def compiled_engine(op: str) -> str:
-    """Which engine ``compiled`` mode runs for ``op``: ``pallas`` iff the
-    probe passed, else ``xla`` (the tile program as plain compiled XLA)."""
-    return ENGINE_PALLAS if compiled_supported(op) else ENGINE_XLA
+    """Which engine ``compiled`` mode runs for ``op`` on this backend."""
+    assert op in OPS, op
+    if platform() == "tpu" and op in PALLAS_ON_TPU:
+        return ENGINE_PALLAS
+    return ENGINE_XLA
 
 
-def probe_report() -> Dict[str, Dict[str, str]]:
-    """Probe every op; return {op: {supported, engine, error?}} — the
-    compiled-kernel smoke tier (``tools/kernel_smoke.py``) prints this."""
+def engine_report() -> Dict[str, str]:
+    """{op: the route the active policy takes} — ``pallas`` / ``xla`` under
+    compiled mode, else ``interpret`` / ``oracle``. ``chip_smoke.py`` and
+    ``tools/kernel_smoke.py`` print it."""
     report = {}
     for op in OPS:
-        ok = compiled_supported(op)
-        entry = {"supported": ok, "engine": compiled_engine(op)}
-        if not ok and op in _PROBE_ERRORS:
-            entry["error"] = _PROBE_ERRORS[op]
-        report[op] = entry
+        mode = kernel_mode(op)
+        report[op] = compiled_engine(op) if mode == MODE_COMPILED else mode
     return report
-
-
-def reset_probe_cache() -> None:
-    """Drop probe results (tests only — e.g. after monkeypatching)."""
-    _PROBE_CACHE.clear()
-    _PROBE_ERRORS.clear()
 
 
 def backend_fingerprint() -> str:
     """Stable identity of (backend, device kind, jax version) — the autotune
     cache key, so tuned shapes never leak across machines or upgrades."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 - no devices (docs builds)
-        kind = "unknown"
+    kind = jax.devices()[0].device_kind
     return f"{jax.default_backend()}:{kind}:jax-{jax.__version__}"
 
 
@@ -220,7 +176,7 @@ def backend_fingerprint() -> str:
 
 def interpret_default() -> bool:
     """True everywhere except a real TPU backend."""
-    return jax.default_backend() != "tpu"
+    return platform() != "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -239,7 +195,7 @@ def dispatch_enabled() -> bool:
         return False
     if os.environ.get("REPRO_FORCE_KERNELS", "0") == "1":
         return True
-    return jax.default_backend() == "tpu"
+    return platform() == "tpu"
 
 
 def ops_kernels_enabled() -> bool:

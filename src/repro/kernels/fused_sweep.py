@@ -48,7 +48,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.panel_qr import panel_qr_math
+from repro.kernels.panel_qr import (
+    householder_in_vmem,
+    panel_qr_math,
+    row_block,
+    t_factor_in_vmem,
+)
 from repro.kernels.wy_apply import wy_apply_math
 
 # Kernel-output field order of the fused panel (matches the SweepState
@@ -187,13 +192,21 @@ def panel_qr_apply_math(W: jax.Array, row_start: jax.Array, *, b: int):
 
 
 def _panel_qr_apply_kernel(rs_ref, w_ref, y_ref, t_ref, r_ref, c_ref, cp_ref,
-                           *, b: int):
-    Y, T, R, C, Cp = panel_qr_apply_math(w_ref[...], rs_ref[0], b=b)
-    y_ref[...] = Y
+                           a_ref, *, b: int, chunk: int):
+    # the in-VMEM panel program on a scratch copy of the panel columns,
+    # the WY apply over the resident window, and the C' rows read back at
+    # the traced row offset through the output ref. Not routed on TPU
+    # (backend.PALLAS_ON_TPU): the window must fit VMEM whole, and Mosaic
+    # wants the traced row offset of a multi-lane-tile load provably
+    # 8-aligned.
+    rs = rs_ref[0, 0]
+    a_ref[...] = w_ref[:, :b]
+    taus, R = householder_in_vmem(a_ref, y_ref, rs, chunk=chunk)
+    T = t_factor_in_vmem(y_ref, taus, chunk=chunk)
     t_ref[...] = T
     r_ref[...] = R
-    c_ref[...] = C
-    cp_ref[...] = Cp
+    c_ref[...] = wy_apply_math(y_ref[...], T, w_ref[...])
+    cp_ref[...] = c_ref[pl.ds(rs, b), :]
 
 
 @functools.partial(jax.jit, static_argnames=("b", "interpret"))
@@ -207,8 +220,9 @@ def panel_qr_apply(W: jax.Array, row_start: jax.Array, b: int, *,
 
     interpret = backend.resolve_interpret(interpret)
     m, w = W.shape
-    rs = jnp.asarray(row_start, jnp.int32).reshape((1,))
-    kernel = functools.partial(_panel_qr_apply_kernel, b=b)
+    rs = jnp.asarray(row_start, jnp.int32).reshape((1, 1))
+    kernel = functools.partial(_panel_qr_apply_kernel, b=b,
+                               chunk=row_block(m))
     grid_spec = pl.GridSpec(
         grid=(),
         in_specs=[
@@ -222,6 +236,7 @@ def panel_qr_apply(W: jax.Array, row_start: jax.Array, b: int, *,
             pl.BlockSpec((m, w), lambda: (0, 0)),
             pl.BlockSpec((b, w), lambda: (0, 0)),
         ],
+        scratch_shapes=[pltpu.VMEM((m, b), W.dtype)],
     )
     return pl.pallas_call(
         kernel,

@@ -4,12 +4,13 @@
 for when). Each call resolves the per-op policy (``backend.kernel_mode`` —
 compiled / interpret / oracle) at trace time and routes accordingly:
 
-* **compiled / pallas** — native non-interpret ``pallas_call`` (Mosaic on
-  TPU, Triton on GPU), chosen when the once-per-process capability probe
-  says this backend lowers the op.
-* **compiled / xla** — the same tile program as plain compiled XLA
-  (``*_xla`` in the kernel modules) where Pallas can't lower natively. No
-  alignment contract: runs at natural shapes, no padding copies.
+* **compiled / pallas** — native non-interpret ``pallas_call`` (Mosaic),
+  the engine on TPU for the ops in ``backend.PALLAS_ON_TPU``.
+* **compiled / xla** — the op's tile program as plain compiled XLA
+  (``*_xla`` in the kernel modules): the engine off TPU, and for
+  ``fused_sweep`` everywhere (``backend.compiled_engine`` is the written
+  policy). No alignment contract: runs at natural shapes, no padding
+  copies.
 * **interpret** — the Pallas interpreter; the validation vehicle, never
   chosen automatically.
 * **oracle** — the pure-jnp reference in ``ref.py``; also the automatic

@@ -22,68 +22,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.panel_qr import unrolled_loop
+from repro.core.householder import MATMUL_PRECISION
+from repro.kernels.panel_qr import (
+    householder_in_vmem,
+    panel_qr_math,
+    row_block,
+    t_factor_in_vmem,
+)
 
 
 def stacked_qr_math(R_top: jax.Array, R_bot: jax.Array, *, b: int,
                     unroll: int = 1):
-    """The combine's tile program on plain arrays: (Y2, T, R) of
-    QR([R_top; R_bot]). Shared by the pallas kernel body and the ``xla``
-    compiled engine so both execute the same floating-point program."""
-    # Build the 2b x b stack in VMEM; the masked column loop preserves the
-    # triangular structure exactly (top block of Y is I, bottom is triu).
+    """The ``xla`` engine's combine program on plain arrays: (Y2, T, R) of
+    QR([R_top; R_bot]) — the masked panel program of ``panel_qr_math`` on
+    the 2b x b stack, which preserves the triangular structure exactly (top
+    block of Y is I, bottom block upper triangular)."""
     cols = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)[:, 0]
     tri = cols[:, None] <= cols[None, :]
     S = jnp.concatenate(
         [jnp.where(tri, R_top, 0.0), jnp.where(tri, R_bot, 0.0)],
         axis=0,
     )
-    m = 2 * b
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)[:, 0]
-    dtype = S.dtype
-
-    def col_step(j, carry):
-        A_, Y_, taus_ = carry
-        mask = rows >= j
-        x = jnp.where(mask, A_[:, j], 0.0)
-        x0 = x[j]
-        sigma = jnp.sum(x * x) - x0 * x0
-        norm_x = jnp.sqrt(x0 * x0 + sigma)
-        sign = jnp.where(x0 >= 0, 1.0, -1.0).astype(dtype)
-        beta = -sign * norm_x
-        degenerate = norm_x <= jnp.asarray(1e-30, dtype)
-        denom = jnp.where(degenerate, 1.0, x0 - beta)
-        v = jnp.where(mask, x / denom, 0.0)
-        v = v.at[j].set(1.0)
-        tau = jnp.where(degenerate, 0.0, (beta - x0) / beta).astype(dtype)
-        w = v @ A_
-        A_ = A_ - tau * v[:, None] * w[None, :]
-        Y_ = Y_.at[:, j].set(v)
-        taus_ = taus_.at[j].set(tau)
-        return A_, Y_, taus_
-
-    A_out, Y, taus = unrolled_loop(b, col_step, (S, S * 0.0, S[0] * 0.0),
-                                   unroll)
-
-    G = Y.T @ Y
-
-    def t_step(j, T):
-        g = jnp.where(cols < j, G[:, j], 0.0)
-        col = -taus[j] * (T @ g)
-        col = jnp.where(cols < j, col, 0.0)
-        col = col.at[j].set(taus[j])
-        return T.at[:, j].set(col)
-
-    T = unrolled_loop(b, t_step, G * 0.0, unroll)
-
-    return (jnp.where(tri, Y[b:, :], 0.0), T, jnp.where(tri, A_out[:b, :], 0.0))
+    Y, T, R = panel_qr_math(S, jnp.asarray(0, jnp.int32), num_cols=b,
+                            unroll=unroll)
+    return jnp.where(tri, Y[b:, :], 0.0), T, R
 
 
-def _stacked_qr_kernel(rt_ref, rb_ref, y2_ref, t_ref, r_ref, *, b: int):
-    Y2, T, R = stacked_qr_math(rt_ref[...], rb_ref[...], b=b)
-    y2_ref[...] = Y2
-    t_ref[...] = T
+def _stacked_qr_kernel(rt_ref, rb_ref, y2_ref, t_ref, r_ref, s_ref, y_ref,
+                       *, b: int):
+    # the 2b x b stack in VMEM scratch, then the in-VMEM panel program
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+           <= jax.lax.broadcasted_iota(jnp.int32, (1, b), 1))
+    s_ref[:b, :] = jnp.where(tri, rt_ref[...], 0.0)
+    s_ref[b:, :] = jnp.where(tri, rb_ref[...], 0.0)
+    chunk = row_block(2 * b)
+    taus, R = householder_in_vmem(s_ref, y_ref, 0, chunk=chunk)
+    y2_ref[...] = jnp.where(tri, y_ref[b:, :], 0.0)
+    t_ref[...] = t_factor_in_vmem(y_ref, taus, chunk=chunk)
     r_ref[...] = R
 
 
@@ -111,6 +88,7 @@ def stacked_qr(R_top: jax.Array, R_bot: jax.Array, *, interpret: bool | None = N
         in_specs=[spec, spec],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((b, b), R_top.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((2 * b, b), R_top.dtype)] * 2,
         interpret=interpret,
     )(R_top, R_bot)
     return Y2, T, R
@@ -119,10 +97,12 @@ def stacked_qr(R_top: jax.Array, R_bot: jax.Array, *, interpret: bool | None = N
 def stacked_apply_math(Y2, T, Ct, Cb):
     """The trailing-combine tile program (f32 accumulation) on plain
     arrays; returns (Ct_hat, Cb_hat, W) in ``Ct.dtype``."""
-    inner = Ct + jnp.dot(Y2.T, Cb, preferred_element_type=jnp.float32)
-    W = jnp.dot(T.T, inner, preferred_element_type=jnp.float32)
+    dot = functools.partial(jnp.dot, precision=MATMUL_PRECISION,
+                            preferred_element_type=jnp.float32)
+    inner = Ct + dot(Y2.T, Cb)
+    W = dot(T.T, inner)
     ot = (Ct - W).astype(Ct.dtype)
-    ob = (Cb - jnp.dot(Y2, W, preferred_element_type=jnp.float32)).astype(Ct.dtype)
+    ob = (Cb - dot(Y2, W)).astype(Ct.dtype)
     return ot, ob, W.astype(Ct.dtype)
 
 
@@ -154,28 +134,23 @@ def stacked_apply(
 ):
     """Fused trailing combine (paper Alg. 2 body). Returns (Ct_hat, Cb_hat, W).
 
-    Y2, T: (b, b); C_top, C_bot: (b, n). Tiled over n.
+    Y2, T: (b, b); C_top, C_bot: (b, n). Tiled over n in ``block_n``
+    columns; every op is column-parallel, so the last block may be partial
+    (its out-of-range columns are never written).
     interpret: None resolves via ``backend.interpret_default()``.
     """
     from repro.kernels import backend
     interpret = backend.resolve_interpret(interpret)
     b, n = C_top.shape
-    n_pad = (-n) % block_n
-    if n_pad:
-        C_top = jnp.pad(C_top, ((0, 0), (0, n_pad)))
-        C_bot = jnp.pad(C_bot, ((0, 0), (0, n_pad)))
-    n_total = n + n_pad
-    grid = (n_total // block_n,)
+    bn = min(block_n, n)
     bspec = pl.BlockSpec((b, b), lambda j: (0, 0))
-    cspec = pl.BlockSpec((b, block_n), lambda j: (0, j))
+    cspec = pl.BlockSpec((b, bn), lambda j: (0, j))
     ot, ob, W = pl.pallas_call(
         _stacked_apply_kernel,
-        grid=grid,
+        grid=(pl.cdiv(n, bn),),
         in_specs=[bspec, bspec, cspec, cspec],
         out_specs=[cspec, cspec, cspec],
-        out_shape=[jax.ShapeDtypeStruct((b, n_total), C_top.dtype)] * 3,
+        out_shape=[jax.ShapeDtypeStruct((b, n), C_top.dtype)] * 3,
         interpret=interpret,
     )(Y2, T, C_top, C_bot)
-    if n_pad:
-        return ot[:, :n], ob[:, :n], W[:, :n]
     return ot, ob, W

@@ -12,24 +12,37 @@ with ``--kill-lane`` as the serve smoke tier).
 from __future__ import annotations
 
 import argparse
+import time
+from typing import Dict
 
 import numpy as np
 
 from repro.core import SimComm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.qr_service import QRService
 
 
 def make_requests(rng: np.random.Generator, count: int, b: int,
                   max_m: int, max_n: int, lstsq_frac: float):
-    """Ragged synthetic traffic: shapes uniform in [b, max]; a fraction
-    carries a right-hand side (the lstsq tenants)."""
+    """Ragged synthetic traffic: shapes uniform in [b, max];
+    ``round(lstsq_frac * count)`` of the requests, at random positions,
+    carry a right-hand side (the lstsq tenants). An lstsq tenant is drawn
+    overdetermined by 2x where the bounds allow (``m >= min(2n, max_m)``),
+    which keeps a Gaussian problem well-conditioned: its solution is then
+    checked to a fixed absolute tolerance."""
+    lstsq = set(rng.choice(count, round(lstsq_frac * count), replace=False)
+                .tolist())
     reqs = []
-    for _ in range(count):
-        m = int(rng.integers(b, max_m + 1))
+    for i in range(count):
         n = int(rng.integers(b, max_n + 1))
+        lo = b
+        if i in lstsq:
+            n = min(n, max_m)
+            lo = max(b, min(2 * n, max_m))
+        m = int(rng.integers(lo, max_m + 1))
         A = rng.standard_normal((m, n)).astype(np.float32)
         rhs = None
-        if rng.random() < lstsq_frac and m >= n:
+        if i in lstsq:
             rhs = rng.standard_normal((m, 2)).astype(np.float32)
         reqs.append((A, rhs))
     return reqs
@@ -56,7 +69,7 @@ def verify(res, A, rhs) -> None:
             f"{np.abs(res.x - x_ref).max():.2e}")
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--lanes", type=int, default=4)
     ap.add_argument("--panel-width", type=int, default=4)
@@ -72,8 +85,13 @@ def main() -> None:
                     help="kill this lane mid-batch (-1 = failure-free)")
     ap.add_argument("--kill-tick", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> Dict[str, float]:
+    """Serve the synthetic burst ``args`` describes, verify every retired
+    result against numpy (raises on a mismatch), and return the summary
+    ``main`` prints."""
     rng = np.random.default_rng(args.seed)
     comm = SimComm(args.lanes)
     b = args.panel_width
@@ -85,7 +103,6 @@ def main() -> None:
     reqs = make_requests(rng, args.requests, b, args.max_m, args.max_n,
                          args.lstsq_frac)
 
-    import time
     pending = list(reqs)
     by_rid = {}
     t0 = time.perf_counter()
@@ -106,15 +123,28 @@ def main() -> None:
     wall = time.perf_counter() - t0
 
     lat = np.array(sorted(r.latency_s for r in svc.results.values()))
-    heals = sum(len(r.events) for r in svc.results.values())
     for rid, (A, rhs) in by_rid.items():
         verify(svc.results[rid], A, rhs)
-    print(f"served {len(svc.results)} requests in {wall:.2f}s "
-          f"({len(svc.results) / wall:.1f} req/s) over {svc.tick_count} "
-          f"ticks; p50 {lat[len(lat) // 2] * 1e3:.1f}ms "
-          f"p99 {lat[min(len(lat) - 1, int(len(lat) * 0.99))] * 1e3:.1f}ms; "
-          f"{heals} tenant REBUILDs; "
-          f"{svc.compiled_programs} resident compiled segments")
+    return {
+        "requests": len(svc.results),
+        "lstsq": sum(rhs is not None for _, rhs in by_rid.values()),
+        "wall_s": wall,
+        "ticks": svc.tick_count,
+        "p50_ms": lat[len(lat) // 2] * 1e3,
+        "p99_ms": lat[min(len(lat) - 1, int(len(lat) * 0.99))] * 1e3,
+        "rebuilds": sum(len(r.events) for r in svc.results.values()),
+        "compiled_segments": svc.compiled_programs,
+    }
+
+
+def main() -> None:
+    enable_compile_cache()
+    s = run(parse_args())
+    print(f"served {s['requests']} requests in {s['wall_s']:.2f}s "
+          f"({s['requests'] / s['wall_s']:.1f} req/s) over {s['ticks']} "
+          f"ticks; p50 {s['p50_ms']:.1f}ms p99 {s['p99_ms']:.1f}ms; "
+          f"{s['rebuilds']} tenant REBUILDs; "
+          f"{s['compiled_segments']} resident compiled segments")
     print("all results verified against numpy QR/lstsq")
 
 

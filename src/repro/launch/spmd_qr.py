@@ -163,7 +163,8 @@ def make_spmd_sweep_step(mesh=None, axis_name: str = "qr"):
     REBUILDs on that global layout with the SimComm mask primitives, while
     every compiled segment runs the AxisComm program on the devices. One
     program is compiled per cursor position (the treedef carries the
-    cursor) and cached for the lifetime of the returned callable.
+    cursor) and cached for the lifetime of the returned callable;
+    ``step.program(state)`` returns it (e.g. to read its compiled text).
 
     Per-leaf specs come from ``state_lane_axes``; the body squeezes each
     leaf's size-1 lane axis so the AxisComm step sees true per-lane locals,
@@ -183,7 +184,8 @@ def make_spmd_sweep_step(mesh=None, axis_name: str = "qr"):
             return P()
         return P(*([None] * lane_axis + [axis_name]))
 
-    def step(state):
+    def program(state):
+        """The jitted shard_map program of ``state``'s sweep point."""
         key = jax.tree_util.tree_structure(state)
         fn = cache.get(key)
         if fn is None:
@@ -207,9 +209,13 @@ def make_spmd_sweep_step(mesh=None, axis_name: str = "qr"):
                 out_specs=jax.tree_util.tree_map(spec_of, out_axes),
             ))
             cache[key] = fn
-        with compat.set_mesh(mesh):
-            return fn(state)
+        return fn
 
+    def step(state):
+        with compat.set_mesh(mesh):
+            return program(state)(state)
+
+    step.program = program
     return step
 
 
@@ -271,6 +277,11 @@ def ft_caqr_sweep_elastic_spmd(
     return orch.run()
 
 
+# One segment runner per (mesh, axis): repeated online sweeps on a mesh share
+# its compiled per-point programs, like ``compiled_segment`` under SimComm.
+_ONLINE_STEPS = {}
+
+
 def ft_caqr_sweep_online_spmd(
     A: jax.Array,
     panel_width: int,
@@ -300,10 +311,13 @@ def ft_caqr_sweep_online_spmd(
     assert m % n_lanes == 0, (
         f"rows ({m}) must block-shard evenly over {n_lanes} lanes"
     )
+    key = (mesh, axis_name)
+    if key not in _ONLINE_STEPS:
+        _ONLINE_STEPS[key] = make_spmd_sweep_step(mesh, axis_name)
     orch = SweepOrchestrator(
         A.reshape(n_lanes, m // n_lanes, n), SimComm(n_lanes), panel_width,
         detector=detector,
-        step_fn=make_spmd_sweep_step(mesh, axis_name),
+        step_fn=_ONLINE_STEPS[key],
         **orchestrator_kw,
     )
     return orch.run()

@@ -12,10 +12,12 @@ from repro.configs import ARCHS, get_config, get_smoke
 from repro.data.pipeline import DataConfig
 from repro.ft.failures import FailureSchedule
 from repro.ft.semantics import Semantics
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import TrainConfig, Trainer
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=sorted(ARCHS))
     ap.add_argument("--full", action="store_true",

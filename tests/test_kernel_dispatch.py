@@ -242,22 +242,29 @@ def test_use_kernels_beats_everything(clean_policy):
     assert not backend.dispatch_enabled()
 
 
-def test_compiled_engine_follows_probe(clean_policy):
-    """compiled resolves to pallas iff the capability probe passes; the
-    probe result is cached per process and resettable for tests."""
-    backend.reset_probe_cache()
-    try:
-        clean_policy.setattr(backend, "_probe_compiled", lambda op: True)
-        assert backend.compiled_engine("panel_qr") == backend.ENGINE_PALLAS
-        backend.reset_probe_cache()
-        clean_policy.setattr(backend, "_probe_compiled", lambda op: False)
-        assert backend.compiled_engine("panel_qr") == backend.ENGINE_XLA
-        report = backend.probe_report()
-        assert set(report) == set(backend.OPS)
-        assert all(e["engine"] == backend.ENGINE_XLA
-                   for e in report.values())
-    finally:
-        backend.reset_probe_cache()
+def test_static_engine_policy_off_tpu(clean_policy):
+    """Off TPU the compiled mode runs the xla engine for every op — a
+    written policy, not the outcome of a probe."""
+    assert backend.platform() != "tpu"
+    for op in backend.OPS:
+        assert backend.compiled_engine(op) == backend.ENGINE_XLA
+    assert backend.engine_report() == {op: backend.ENGINE_XLA
+                                       for op in backend.OPS}
+
+
+def test_tpu_lowering_failure_raises(clean_policy, rng):
+    """On a TPU-like backend the tile ops route to native Pallas and
+    ``fused_sweep`` to xla; a Pallas kernel that cannot lower (here: the
+    CPU backend underneath) raises its lowering error — no fallback to
+    another engine."""
+    clean_policy.setattr(backend, "platform", lambda: "tpu")
+    for op in backend.OPS:
+        want = (backend.ENGINE_PALLAS if op in backend.PALLAS_ON_TPU
+                else backend.ENGINE_XLA)
+        assert backend.compiled_engine(op) == want
+    A = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
+    with pytest.raises(Exception, match="(?i)interpret"):
+        jax.block_until_ready(ops.panel_qr(A, 0))
 
 
 def test_oracle_route_for_unsupported_dtype(clean_policy, rng):

@@ -1,11 +1,11 @@
 """Per-kernel shape sweeps vs the pure-jnp oracles, across execution routes.
 
 The per-op policy (DESIGN.md §10) gives every op three executions: compiled
-(engine ``pallas`` where the backend lowers it, else ``xla``), the Pallas
+(engine ``pallas`` on TPU, else ``xla`` — a static policy), the Pallas
 interpreter, and the jnp oracle. The sweeps here force each non-oracle mode
 in turn and gate it against the oracle at ``ref.tolerances(dtype)``; the
 ragged parity matrix adds odd/unaligned shapes and bf16. Native-pallas
-cells run only where the capability probe passes (loud skip elsewhere).
+cells run only where the policy runs Pallas (loud skip elsewhere).
 
 Stacked-op inputs are QR-derived R factors, not raw ``triu`` of a Gaussian:
 a random upper-triangular matrix is exponentially ill-conditioned (cond
@@ -112,10 +112,10 @@ def test_parity_matrix_ragged(rng, route, dtype, m, b, n):
 
 @pytest.mark.parametrize("op", backend.OPS)
 def test_native_pallas_parity(rng, op):
-    """The pallas engine itself, where this backend lowers it (skipped
-    elsewhere — tools/kernel_smoke.py reports which, loudly)."""
-    if not backend.compiled_supported(op):
-        pytest.skip(f"backend does not lower native Pallas for {op}")
+    """The pallas engine itself, where the static policy runs it (TPU;
+    skipped elsewhere — tools/kernel_smoke.py reports which, loudly)."""
+    if backend.compiled_engine(op) != backend.ENGINE_PALLAS:
+        pytest.skip(f"the policy runs no native Pallas for {op} here")
     backend.force_mode(backend.MODE_COMPILED, op)
     try:
         if op == "panel_qr":

@@ -41,6 +41,7 @@ from repro.ft.online.detect import (
 from repro.ft.online.state import (
     finalize,
     initial_sweep_state,
+    run_steps,
     sweep_state_from_host,
     sweep_state_to_host,
     sweep_step,
@@ -106,6 +107,28 @@ def test_stepped_iteration_matches_monolithic(shape):
     for g, r in zip(_leaves(R, factors, bundles),
                     _leaves(ref.R, ref.factors, ref.bundles)):
         assert np.array_equal(g, r)
+
+
+def test_segment_forwards_unchanged_leaves():
+    """A compiled segment returns the leaves it does not compute as the
+    caller's own arrays (no device copy of the source matrix or of the
+    stored panels), and its computed leaves equal a plain jit's."""
+    from repro.ft.online.orchestrator import compiled_segment
+
+    comm = SimComm(4)
+    s = initial_sweep_state(comm, _matrix(4, 8, 16, seed=3), 4)
+    seg = compiled_segment(comm, 1)
+    while s.cursor != (1, "tsqr", 0):
+        s = seg(s)
+    out = seg(s)
+    assert out.A0 is s.A0 and out.A is s.A and out.window is s.window
+    assert all(o is i for o, i in zip(jax.tree_util.tree_leaves(out.bundles),
+                                      jax.tree_util.tree_leaves(s.bundles)))
+    want = jax.jit(functools.partial(run_steps, comm, max_points=1))(s)
+    assert out.cursor == want.cursor
+    for g, w in zip(jax.tree_util.tree_leaves(out),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_cursor_arithmetic_round_trip():

@@ -3,10 +3,10 @@
 
 Three checks, in order:
 
-1. **Capability probe report** — what ``backend._probe_compiled`` found for
-   every op on this backend: which ops lower native Pallas, which fall back
-   to the ``xla`` engine, and the probe error when they do. Purely
-   informational, always printed.
+1. **Engine report** — the route the static policy
+   (``backend.compiled_engine``) gives every op on this backend: native
+   Pallas on TPU for the tile kernels, the ``xla`` engine elsewhere.
+   Purely informational, always printed.
 2. **Compiled-dispatch parity** — run every op through the real ``ops``
    dispatch under the active policy (whatever engine ``compiled`` resolves
    to here) on an aligned and a ragged geometry, in f32 and bf16, and
@@ -17,12 +17,11 @@ Three checks, in order:
    clear, load, and require the looked-up params to be identical (the
    persistence format and the fingerprint keying actually work).
 
-When no op lowers native Pallas the tier prints a LOUD skip for the
-pallas-engine half (the xla-engine parity still runs — that is the compiled
-path CI actually exercises on CPU images). ``CI_REQUIRE_COMPILED_KERNELS=1``
-turns that skip into an error for images that are supposed to have a
-Mosaic/Triton toolchain. Exit codes: 0 OK / 1 failure (or required-but-
-missing native Pallas).
+When no op runs native Pallas (any backend but TPU) the tier prints a
+LOUD skip for the pallas-engine half (the xla-engine parity still runs —
+that is the compiled path CI actually exercises on CPU images).
+``CI_REQUIRE_COMPILED_KERNELS=1`` turns that skip into an error. Exit
+codes: 0 OK / 1 failure (or required-but-missing native Pallas).
 """
 from __future__ import annotations
 
@@ -40,17 +39,14 @@ def main() -> int:
     from repro.kernels import autotune, backend, fused_sweep, ops, ref
 
     print(f"backend fingerprint: {backend.backend_fingerprint()}")
-    report = backend.probe_report()
-    native = [op for op, e in report.items() if e["supported"]]
-    for op, entry in report.items():
-        line = f"  {op:14s} engine={entry['engine']}"
-        if not entry["supported"]:
-            err = entry.get("error", "").splitlines()[0][:80]
-            line += f"  (native pallas probe failed: {err})"
-        print(line)
+    report = backend.engine_report()
+    native = [op for op, engine in report.items()
+              if engine == backend.ENGINE_PALLAS]
+    for op, engine in report.items():
+        print(f"  {op:14s} engine={engine}")
 
     if not native:
-        print("LOUD SKIP: no op lowers native Pallas on this backend — the "
+        print("LOUD SKIP: no op runs native Pallas on this backend — the "
               "pallas engine is untested here; compiled dispatch runs via "
               "the xla engine below.")
         if os.environ.get("CI_REQUIRE_COMPILED_KERNELS") == "1":
