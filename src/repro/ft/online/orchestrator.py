@@ -42,11 +42,13 @@ masking runs through the SimComm primitives on the identical global layout.
 from __future__ import annotations
 
 import functools
+import itertools
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.comm import SimComm
 from repro.ft.coding import CodingScheme, XORPairScheme
@@ -82,6 +84,15 @@ _SEGMENT_CACHE: Dict[Tuple, Callable] = {}
 
 FaultHook = Callable[[object, SweepState], SweepState]
 BoundaryHook = Callable[["SweepOrchestrator"], None]
+
+# Host spans of the sweep loop, recorded into the profiler's trace (no-ops
+# when no profiler runs): ``ftqr.sweep`` around one factorization, and
+# inside it ``ftqr.dispatch`` per segment enqueue, ``ftqr.poll`` per
+# detector poll and ``ftqr.heal`` per recovery. ``bench/program_spans.py``
+# splits the device's idle time among them (``idle_share.dispatch``,
+# ``.poll``, ``.loop``, ``.heal``, ``heal_ops``). ``sweep`` ids tie the
+# spans to their factorization.
+_SWEEP_IDS = itertools.count()
 
 
 def forwarding_jit(f: Callable):
@@ -325,8 +336,11 @@ class SweepOrchestrator:
         # run statistics (benchmarks read these)
         self.segments_run = 0
         self.boundaries = 0
+        # host seconds inside the detector polls, including the wait for
+        # the segment the poll reads; what the poll costs the device is the
+        # device idle under the ``ftqr.poll`` spans (``idle_share.poll`` of
+        # ``bench/program_spans.py``)
         self.poll_s = 0.0
-        self.recover_s = 0.0
 
     @classmethod
     def from_state(cls, state: SweepState, comm, **kw) -> "SweepOrchestrator":
@@ -363,15 +377,18 @@ class SweepOrchestrator:
         return fn(state)
 
     def _segment(self, state: SweepState) -> SweepState:
-        if self.step_fn is not None:
-            for _ in range(self.segment_points):
-                if state.cursor is None:
-                    break
-                state = self.step_fn(state)
-            return state
-        if self.fused:
-            return self._fused_segment(state)
-        return self._stepped(state, self.segment_points)
+        panel, phase, level = state.cursor
+        with TraceAnnotation("ftqr.dispatch", panel=panel, phase=phase,
+                             level=level):
+            if self.step_fn is not None:
+                for _ in range(self.segment_points):
+                    if state.cursor is None:
+                        break
+                    state = self.step_fn(state)
+                return state
+            if self.fused:
+                return self._fused_segment(state)
+            return self._stepped(state, self.segment_points)
 
     def _finalize(self):
         if not self.jit_segments:
@@ -387,11 +404,20 @@ class SweepOrchestrator:
         Under SHRINK/BLANK semantics returns ``ElasticSweepResult``
         instead — epochs at different world sizes have no common lane
         layout for factors, so R is host-spliced."""
-        if self._resumed:
-            self._resumed = False
-            self._resume_boundary_pass()
-        if self.async_segments:
-            return self._run_async()
+        geom = self.state.geom
+        with TraceAnnotation("ftqr.sweep", sweep=next(_SWEEP_IDS),
+                             lanes=self.comm.axis_size(), m_loc=geom.m_loc,
+                             n=geom.n):
+            if self._resumed:
+                self._resumed = False
+                self._resume_boundary_pass()
+            if self.async_segments:
+                return self._run_async()
+            return self._run_sync()
+
+    def _run_sync(self):
+        """The sync loop: per boundary [segment, refresh, hooks, poll,
+        recover, boundary hooks]."""
         boundary = 0
         while True:
             # re-read per iteration: an elastic transition swaps in a new
@@ -412,9 +438,7 @@ class SweepOrchestrator:
             point = prev_sweep_point(self.state.cursor, geom.n_panels, levels)
             for hook in self.fault_hooks:
                 self.state = hook(self.comm, self.state)
-            t0 = time.perf_counter()
-            newly = list(self.detector.poll(self.comm, self.state))
-            self.poll_s += time.perf_counter() - t0
+            newly = self._poll(self.state, boundary)
             if newly:
                 self._recover(newly, point)
             if (self.straggler_monitor is not None
@@ -458,21 +482,27 @@ class SweepOrchestrator:
             return  # resumed at the very first point: nothing completed yet
         for hook in self.fault_hooks:
             self.state = hook(self.comm, self.state)
-        t0 = time.perf_counter()
-        newly = list(self.detector.poll(self.comm, self.state))
-        self.poll_s += time.perf_counter() - t0
+        newly = self._poll(self.state, 0)
         if newly:
             self._recover(newly, point)
 
-    def _poll_async(self, state: SweepState) -> List[int]:
-        """One detector poll through the split ``probe``/``collect`` form
-        when the detector has it (``NaNSentinelDetector``): the caller
-        dispatches device work between probe dispatch and collect. Plain
-        ``poll`` is the fallback for protocol-only detectors."""
-        probe = getattr(self.detector, "probe", None)
-        if probe is None:
-            return list(self.detector.poll(self.comm, state))
-        return list(self.detector.collect(self.comm, probe(self.comm, state)))
+    def _poll(self, state: SweepState, boundary: int, split: bool = False
+              ) -> List[int]:
+        """One detector poll, under its ``ftqr.poll`` span. ``split`` (the
+        async loop) takes the split ``probe``/``collect`` form when the
+        detector has it (``NaNSentinelDetector``): the caller dispatches
+        device work between probe dispatch and collect. Plain ``poll`` is
+        the fallback for protocol-only detectors."""
+        with TraceAnnotation("ftqr.poll", boundary=boundary):
+            t0 = time.perf_counter()
+            probe = getattr(self.detector, "probe", None) if split else None
+            if probe is None:
+                newly = list(self.detector.poll(self.comm, state))
+            else:
+                newly = list(self.detector.collect(self.comm,
+                                                   probe(self.comm, state)))
+            self.poll_s += time.perf_counter() - t0
+        return newly
 
     def _run_async(self) -> FTSweepResult:
         """The double-buffered segment loop (async mode).
@@ -508,11 +538,9 @@ class SweepOrchestrator:
                 # quiet so far: dispatch the next segment ahead of the
                 # (blocking) detector collect — the double buffer
                 spec = self._segment(cur)
-            t0 = time.perf_counter()
-            newly = self._poll_async(cur)
-            self.poll_s += time.perf_counter() - t0
             boundary += 1
             self.boundaries += 1
+            newly = self._poll(cur, boundary, split=True)
             self.state = cur
             if newly:
                 spec = None  # speculated from a state recovery rewrites
@@ -650,46 +678,49 @@ class SweepOrchestrator:
             self.elastic.note_deaths(newly)
 
     def _heal(self, newly: List[int], point) -> None:
-        dead = set(newly)
-        shardings = None
-        if self.step_fn is not None:
-            # The REBUILD replay must be bitwise-identical to the SimComm
-            # oracle, but on the shard_map path the state lives as
-            # lane-sharded global arrays: eager replay math on those
-            # compiles auto-sharded executables whose reduction order
-            # drifts from the single-device programs by ~1 ulp. Gather to
-            # one device for the heal and shard back after — both pure
-            # data movement.
-            shardings = jax.tree_util.tree_map(
-                lambda x: x.sharding, self.state)
-            dev = jax.devices()[0]
-            self.state = jax.tree_util.tree_map(
-                lambda x: jax.device_put(x, dev), self.state)
+        panel, phase, level = point
+        with TraceAnnotation("ftqr.heal",
+                             lanes=" ".join(str(l) for l in sorted(newly)),
+                             panel=panel, phase=phase, level=level):
+            dead = set(newly)
+            shardings = None
+            if self.step_fn is not None:
+                # The REBUILD replay must be bitwise-identical to the SimComm
+                # oracle, but on the shard_map path the state lives as
+                # lane-sharded global arrays: eager replay math on those
+                # compiles auto-sharded executables whose reduction order
+                # drifts from the single-device programs by ~1 ulp. Gather to
+                # one device for the heal and shard back after — both pure
+                # data movement.
+                shardings = jax.tree_util.tree_map(
+                    lambda x: x.sharding, self.state)
+                dev = jax.devices()[0]
+                self.state = jax.tree_util.tree_map(
+                    lambda x: jax.device_put(x, dev), self.state)
 
-        def on_recovered(lane: int) -> None:
-            dead.discard(lane)
-            # announce the respawn so the detector re-arms for this lane
-            # immediately (back-to-back deaths at consecutive boundaries
-            # must still be seen)
-            revive = getattr(self.detector, "revive", None)
-            if revive is not None:
-                revive(lane)
+            def on_recovered(lane: int) -> None:
+                dead.discard(lane)
+                # announce the respawn so the detector re-arms for this lane
+                # immediately (back-to-back deaths at consecutive boundaries
+                # must still be seen)
+                revive = getattr(self.detector, "revive", None)
+                if revive is not None:
+                    revive(lane)
 
-        # the SAME strike-then-rebuild protocol as the scheduled driver's
-        # checkpoint — shared code, so the scheduled-vs-online bitwise
-        # equivalence cannot drift apart in one copy
-        self.state, events = recover_lanes(
-            self.comm, self.state, newly, point, dead,
-            sync=lambda s: jax.block_until_ready(
-                jax.tree_util.tree_leaves(s)),
-            on_recovered=on_recovered,
-            scheme=self.scheme,
-        )
-        if shardings is not None:
-            self.state = jax.tree_util.tree_map(
-                jax.device_put, self.state, shardings)
-        self.recover_s += sum(e.elapsed_s for e in events)
-        self.events.extend(events)
+            # the SAME strike-then-rebuild protocol as the scheduled driver's
+            # checkpoint — shared code, so the scheduled-vs-online bitwise
+            # equivalence cannot drift apart in one copy
+            self.state, events = recover_lanes(
+                self.comm, self.state, newly, point, dead,
+                sync=lambda s: jax.block_until_ready(
+                    jax.tree_util.tree_leaves(s)),
+                on_recovered=on_recovered,
+                scheme=self.scheme,
+            )
+            if shardings is not None:
+                self.state = jax.tree_util.tree_map(
+                    jax.device_put, self.state, shardings)
+            self.events.extend(events)
 
 
 def ft_caqr_sweep_online(

@@ -173,6 +173,7 @@ def fused_panel_pallas(window: jax.Array, *, k: int, b: int, m_loc_pad: int,
         kernel,
         out_shape=[jax.ShapeDtypeStruct(shapes[f], dt) for f in FUSED_FIELDS],
         interpret=interpret,
+        name="fused_sweep",
     )(window)
     result = dict(zip(FUSED_FIELDS, outs))
     result["tops"] = _tops(P, (k * b) // m_loc_pad, levels)
@@ -249,6 +250,7 @@ def panel_qr_apply(W: jax.Array, row_start: jax.Array, b: int, *,
             jax.ShapeDtypeStruct((b, w), W.dtype),
         ],
         interpret=interpret,
+        name="fused_sweep",
     )(rs, W)
 
 

@@ -317,5 +317,6 @@ def panel_qr(A: jax.Array, row_start: jax.Array, *, interpret: bool | None = Non
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit(5 * m * b * A.dtype.itemsize)),
         interpret=interpret,
+        name="panel_qr",
     )(rs, A)
     return Y, T, R

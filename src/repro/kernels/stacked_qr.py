@@ -90,6 +90,7 @@ def stacked_qr(R_top: jax.Array, R_bot: jax.Array, *, interpret: bool | None = N
         out_shape=[jax.ShapeDtypeStruct((b, b), R_top.dtype)] * 3,
         scratch_shapes=[pltpu.VMEM((2 * b, b), R_top.dtype)] * 2,
         interpret=interpret,
+        name="stacked_qr",
     )(R_top, R_bot)
     return Y2, T, R
 
@@ -152,5 +153,6 @@ def stacked_apply(
         out_specs=[cspec, cspec, cspec],
         out_shape=[jax.ShapeDtypeStruct((b, n), C_top.dtype)] * 3,
         interpret=interpret,
+        name="stacked_apply",
     )(Y2, T, C_top, C_bot)
     return ot, ob, W

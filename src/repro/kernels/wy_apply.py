@@ -120,4 +120,5 @@ def wy_apply(
             vmem_limit_bytes=vmem_limit(
                 2 * itemsize * (bm * b + b * b + 2 * bm * bn) + 4 * b * bn)),
         interpret=interpret,
+        name="wy_apply",
     )(Y, T, C)
