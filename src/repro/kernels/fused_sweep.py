@@ -51,7 +51,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.panel_qr import (
     householder_in_vmem,
     panel_qr_math,
-    row_block,
     t_factor_in_vmem,
 )
 from repro.kernels.wy_apply import wy_apply_math
@@ -193,17 +192,16 @@ def panel_qr_apply_math(W: jax.Array, row_start: jax.Array, *, b: int):
 
 
 def _panel_qr_apply_kernel(rs_ref, w_ref, y_ref, t_ref, r_ref, c_ref, cp_ref,
-                           a_ref, *, b: int, chunk: int):
-    # the in-VMEM panel program on a scratch copy of the panel columns,
-    # the WY apply over the resident window, and the C' rows read back at
+                           *, b: int):
+    # the in-VMEM panel program on the panel columns of the window, the
+    # WY apply over the resident window, and the C' rows read back at
     # the traced row offset through the output ref. Not routed on TPU
     # (backend.PALLAS_ON_TPU): the window must fit VMEM whole, and Mosaic
     # wants the traced row offset of a multi-lane-tile load provably
     # 8-aligned.
     rs = rs_ref[0, 0]
-    a_ref[...] = w_ref[:, :b]
-    taus, R = householder_in_vmem(a_ref, y_ref, rs, chunk=chunk)
-    T = t_factor_in_vmem(y_ref, taus, chunk=chunk)
+    taus, R = householder_in_vmem(w_ref.at[:, :b], y_ref, rs)
+    T = t_factor_in_vmem(y_ref, taus)
     t_ref[...] = T
     r_ref[...] = R
     c_ref[...] = wy_apply_math(y_ref[...], T, w_ref[...])
@@ -222,8 +220,7 @@ def panel_qr_apply(W: jax.Array, row_start: jax.Array, b: int, *,
     interpret = backend.resolve_interpret(interpret)
     m, w = W.shape
     rs = jnp.asarray(row_start, jnp.int32).reshape((1, 1))
-    kernel = functools.partial(_panel_qr_apply_kernel, b=b,
-                               chunk=row_block(m))
+    kernel = functools.partial(_panel_qr_apply_kernel, b=b)
     grid_spec = pl.GridSpec(
         grid=(),
         in_specs=[
@@ -237,7 +234,6 @@ def panel_qr_apply(W: jax.Array, row_start: jax.Array, b: int, *,
             pl.BlockSpec((m, w), lambda: (0, 0)),
             pl.BlockSpec((b, w), lambda: (0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((m, b), W.dtype)],
     )
     return pl.pallas_call(
         kernel,

@@ -17,7 +17,8 @@ compiled / interpret / oracle) at trace time and routes accordingly:
   route for dtypes outside the kernels' envelope (f32 and bf16 are in).
 
 The *pallas* routes enforce the alignment contract (rows in
-``backend.sublane(dtype)`` multiples, panel widths in lane-pad multiples)
+``backend.sublane(dtype)`` multiples — the panel QR's in lane multiples,
+as it holds its tile transposed — panel widths in lane-pad multiples)
 by zero-padding up to it and slicing back — padding with zeros is exact in
 exact arithmetic for every op in this family (extra zero rows/columns
 produce degenerate reflectors with tau = 0 and contribute nothing to any
@@ -108,22 +109,24 @@ def panel_qr(A: jax.Array, row_start=0, *,
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(Y, T, R) of the masked Householder panel QR of A (m, b).
 
-    ``row_start`` may be traced; padding uses only static shape info
-    (rows pad by ``b_pad - b`` extra so the kernel's R extraction at any
-    legal row_start <= m - b stays in bounds). ``unroll`` is the ``xla``
+    ``row_start`` may be traced; a negative one counts as 0 (every row
+    live). Padding uses only static shape info: the kernel holds the tile
+    transposed, rows along lanes, so rows pad to a lane multiple (by
+    ``b_pad - b`` extra, so the kernel's R extraction at any legal
+    row_start <= m - b stays in bounds). ``unroll`` is the ``xla``
     engine's column-loop unroll factor (autotuned when not given).
     """
+    rs = jnp.maximum(jnp.asarray(row_start, jnp.int32), 0)
     route = _route("panel_qr", A)
     if route == _R_ORACLE:
-        return ref.panel_qr(A, row_start)
-    rs = jnp.asarray(row_start, jnp.int32)
+        return ref.panel_qr(A, rs)
     if route == _R_XLA:
         u = _unroll("panel_qr", A.shape, A.dtype, route, unroll)
         return _panel.panel_qr_xla(A, rs, unroll=u)
     m, b = A.shape
     lane = _lane_pad("panel_qr", (m, b), A.dtype, route, lane_pad)
     b_pad = backend.pad_to(b, lane)
-    m_pad = backend.pad_to(m + (b_pad - b), backend.sublane(A.dtype))
+    m_pad = backend.pad_to(m + (b_pad - b), backend.LANE)
     interp = route == _R_INTERPRET
     if (m_pad, b_pad) == (m, b):
         return _panel.panel_qr(A, rs, interpret=interp)

@@ -7,21 +7,26 @@ vectors), T (upper triangular) and R.
 TPU adaptation notes (vs. the CPU/GPU panel kernels the paper's MPI code
 would call):
   * the whole tile is VMEM-resident — one HBM read of A, one write of
-    (Y, T, R); the column loop streams row blocks of the resident tile
-    through the vector units with no HBM traffic, which is what makes the
-    panel latency- rather than bandwidth-bound on TPU;
+    (Y, T, R); the column loop streams the resident tile through the
+    vector units with no HBM traffic, which is what makes the panel
+    latency- rather than bandwidth-bound on TPU;
+  * the column loop runs on the tile transposed once into VMEM scratch,
+    rows along lanes: a column is one row of the scratch, its norm and
+    reflector are lane-dense elementwise work, and ``w = A^T v`` reduces
+    across lanes once per row of the scratch rather than once per block
+    and column;
   * the masked-pivot formulation (pivot row = row_start + j, rows above
-    row_start frozen) is expressed with iota masks only: the traced column
-    index selects through a lane mask, the traced pivot row through a row
-    mask, and the tile is read and written in static-size row blocks
-    (``pl.ds``) — Mosaic lowers no dynamic slice of a value;
-  * m, b should be multiples of (8, 128) for full lane utilization; the
-    ``ops`` wrapper pads when they are not.
+    row_start frozen) is expressed with iota masks and ref slices only:
+    the traced pivot row selects through a lane mask, the traced column
+    through a row offset of the scratch ref (``pl.ds``) — Mosaic lowers no
+    dynamic slice of a value;
+  * m should be a multiple of 128 (rows are lanes of the scratch) and b of
+    128 for full tiling; the ``ops`` wrapper pads when they are not.
 
 Working set: A (double-buffered input), Y (double-buffered output) and the
-working copy of A, 5 * m * b words, plus (b, b) T and R. At m = 16384,
-b = 128 that is 40 MiB — above the default scoped-VMEM limit, so the kernel
-sets its own (``vmem_limit``).
+transposed working tile and Y, 6 * m * b words, plus (b, b) T and R. At
+m = 16384, b = 128 that is 48 MiB — above the default scoped-VMEM limit,
+so the kernel sets its own (``vmem_limit``).
 """
 from __future__ import annotations
 
@@ -57,6 +62,27 @@ def row_block(m: int, cap: int = 512) -> int:
             return c
         c //= 2
     return m
+
+
+# Vregs of the transposed tile that one step of the column update may
+# cover (``update_rows``).
+_UPDATE_VREGS = 512
+
+
+def update_rows(m: int, b: int, sub: int = 8) -> int:
+    """Rows of the transposed (b, m) tile that one step of the column
+    update covers, ``b`` padded to a ``sub`` multiple: the most that divide
+    it and hold at most ``_UPDATE_VREGS`` vregs of ``sub`` x 128 words,
+    ``sub`` at the least. A step costs a fixed latency (its lane reduction)
+    besides its work, and updates the finished rows it holds for nothing:
+    on a v5e the 15744-row leaf tile is fastest in steps of 32 rows, the
+    1024- and 256-row tiles (the service's buckets, the tree combine) in
+    one step of the whole tile. The step changes no output bit."""
+    bs = -(-b // sub) * sub
+    for rows in range(bs, sub - 1, -sub):
+        if bs % rows == 0 and rows * m <= _UPDATE_VREGS * sub * 128:
+            return rows
+    return sub
 
 
 def unrolled_loop(num_steps: int, body, init, unroll: int = 1):
@@ -135,97 +161,86 @@ def panel_qr_math(A: jax.Array, row_start: jax.Array, *, num_cols: int,
 # -- the in-VMEM program (pallas kernel bodies) ------------------------------
 
 
-def householder_in_vmem(w_ref, y_ref, row_start, *, chunk: int):
-    """Masked Householder QR of the (m, b) tile held in ``w_ref``, in place.
+def householder_in_vmem(a_ref, y_ref, row_start):
+    """Masked Householder QR of the (m, b) tile in ``a_ref``.
 
-    On return ``w_ref`` holds the transformed tile and ``y_ref`` the
-    Householder vectors (zero above each pivot). Returns ``(taus, R)``:
-    the (1, b) reflector scales and the (b, b) upper-triangular R — rows
-    ``[row_start, row_start + b)`` of the transformed tile (the start
-    clamped into the tile like ``lax.dynamic_slice``), read through the ref.
+    Writes the Householder vectors (zero above each pivot) to ``y_ref`` and
+    returns ``(taus, R)``: the (1, b) reflector scales and the (b, b)
+    upper-triangular R — rows ``[row_start, row_start + b)`` of the
+    transformed tile (the start clamped into the tile like
+    ``lax.dynamic_slice``), read through ``y_ref`` before Y lands there.
 
-    Three passes over ``chunk``-row blocks per column: the masked column
-    norm, the reflector (written to Y) with its row vector ``w = v^T A``,
-    and the rank-1 update. The traced column ``j`` selects through a lane
-    mask and the pivot row through a row mask, so nothing slices a value at
-    a traced offset."""
-    m, b = w_ref.shape
-    dt = w_ref.dtype
-    n_chunks = m // chunk
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
-    sub = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    The column loop runs on the tile held transposed in VMEM scratch, rows
+    along lanes: column ``j`` of A is row ``j`` of the scratch, so the
+    masked norm, the pivot and the reflector are lane-dense elementwise ops
+    with one final reduction each. Each column then makes one pass over the
+    scratch in steps of ``update_rows`` rows, from the step holding row
+    ``j`` down: ``w`` of the step's rows (multiply, accumulate across
+    lanes, one lane reduction per row) and their rank-1 update. Steps above
+    hold finished columns and are not touched; finished rows inside the
+    first step change only at lanes past their own pivot, which nothing
+    reads. The traced column is read, and its reflector written, as one
+    row of the scratch refs at a traced offset (``pl.ds``); nothing slices
+    a value at a traced offset."""
+    m, b = a_ref.shape
+    dt = a_ref.dtype
+    sub = 32 // dt.itemsize          # rows of one (sub, 128) vreg tile
+    bs = -(-b // sub) * sub
+    grp = update_rows(m, b, sub)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    blane = jax.lax.broadcasted_iota(jnp.int32, (1, b), 1)
     bsub = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
-    zero11 = jnp.zeros((1, 1), dt)
 
-    def block(c):
-        r0 = pl.multiple_of(c * chunk, chunk)
-        return pl.ds(r0, chunk), r0 + sub
+    def factor(at_ref, yt_ref, v_ref):
+        # rows b..bs-1 pad the scratch to whole (sub, 128) tiles; each row
+        # of the update reads only itself, and no result reads these
+        at_ref[:b, :] = a_ref[...].T
 
-    def clear_y(c, carry):
-        rs, _ = block(c)
-        y_ref[rs, :] = jnp.zeros((chunk, b), dt)
-        return carry
+        def col_step(j, taus):
+            pivot = row_start + j
+            x = jnp.where(lane >= pivot, at_ref[pl.ds(j, 1), :], 0.0)
+            x0 = jnp.sum(jnp.where(lane == pivot, x, 0.0), keepdims=True)
+            sigma = jnp.sum(x * x, keepdims=True) - x0 * x0
+            norm_x = jnp.sqrt(x0 * x0 + sigma)
+            sign = jnp.where(x0 >= 0, 1.0, -1.0).astype(dt)
+            beta = -sign * norm_x
+            degenerate = norm_x <= jnp.asarray(1e-30, dt)
+            denom = jnp.where(degenerate, 1.0, x0 - beta)
+            tau = jnp.where(degenerate, 0.0, (beta - x0) / beta).astype(dt)
+            v = jnp.where(lane >= pivot, x / denom, 0.0)
+            v = jnp.where(lane == pivot, 1.0, v).astype(dt)
+            yt_ref[pl.ds(j, 1), :] = v
+            v_ref[...] = jnp.broadcast_to(v, (grp, m))
 
-    jax.lax.fori_loop(0, n_chunks, clear_y, 0)
+            def update(h, carry):
+                rs = pl.ds(pl.multiple_of(h * grp, grp), grp)
+                vv = v_ref[...]
+                w = jnp.sum(at_ref[rs, :] * vv, axis=1, keepdims=True)
+                at_ref[rs, :] = at_ref[rs, :] - (tau * w) * vv
+                return carry
 
-    def col_step(j, taus):
-        pivot = row_start + j
-        colmask = lane == j
+            jax.lax.fori_loop(j // grp, bs // grp, update, 0)
+            return jnp.where(blane == j, tau, taus)
 
-        def column(blk, rows):
-            x = jnp.sum(jnp.where(colmask, blk, 0.0), axis=1, keepdims=True)
-            return jnp.where(rows >= pivot, x, 0.0)
+        taus = jax.lax.fori_loop(0, b, col_step, jnp.zeros((1, b), dt))
+        # the transformed tile in (m, b) layout, for R; then Y over it
+        y_ref[...] = at_ref[:b, :].T
+        R = y_ref[pl.ds(jnp.clip(row_start, 0, m - b), b), :]
+        y_ref[...] = yt_ref[:b, :].T
+        return taus, jnp.where(bsub <= blane, R, 0.0)
 
-        def norm_pass(c, acc):
-            ss, x0 = acc
-            rs, rows = block(c)
-            x = column(w_ref[rs, :], rows)
-            return (ss + jnp.sum(x * x, keepdims=True),
-                    x0 + jnp.sum(jnp.where(rows == pivot, x, 0.0),
-                                 keepdims=True))
-
-        ss, x0 = jax.lax.fori_loop(0, n_chunks, norm_pass, (zero11, zero11))
-        sigma = ss - x0 * x0
-        norm_x = jnp.sqrt(x0 * x0 + sigma)
-        sign = jnp.where(x0 >= 0, 1.0, -1.0).astype(dt)
-        beta = -sign * norm_x
-        degenerate = norm_x <= jnp.asarray(1e-30, dt)
-        denom = jnp.where(degenerate, 1.0, x0 - beta)
-        tau = jnp.where(degenerate, 0.0, (beta - x0) / beta).astype(dt)
-
-        def reflect_pass(c, wrow):
-            rs, rows = block(c)
-            blk = w_ref[rs, :]
-            v = jnp.where(rows >= pivot, column(blk, rows) / denom, 0.0)
-            v = jnp.where(rows == pivot, 1.0, v).astype(dt)
-            y_ref[rs, :] = jnp.where(colmask, v, y_ref[rs, :])
-            return wrow + jnp.sum(v * blk, axis=0, keepdims=True)
-
-        wrow = jax.lax.fori_loop(0, n_chunks, reflect_pass,
-                                 jnp.zeros((1, b), dt))
-
-        def update_pass(c, carry):
-            rs, _ = block(c)
-            v = jnp.sum(jnp.where(colmask, y_ref[rs, :], 0.0), axis=1,
-                        keepdims=True)
-            w_ref[rs, :] = w_ref[rs, :] - tau * v * wrow
-            return carry
-
-        jax.lax.fori_loop(0, n_chunks, update_pass, 0)
-        return jnp.where(colmask, tau, taus)
-
-    taus = jax.lax.fori_loop(0, b, col_step, jnp.zeros((1, b), dt))
-    R = w_ref[pl.ds(jnp.clip(row_start, 0, m - b), b), :]
-    return taus, jnp.where(bsub <= lane, R, 0.0)
+    return pl.run_scoped(factor, pltpu.VMEM((bs, m), dt),
+                         pltpu.VMEM((bs, m), dt), pltpu.VMEM((grp, m), dt))
 
 
-def t_factor_in_vmem(y_ref, taus, *, chunk: int):
+def t_factor_in_vmem(y_ref, taus):
     """Upper-triangular T of ``Q = I - Y T Y^T`` from the Y held in
     ``y_ref`` and the (1, b) ``taus``: the forward recurrence over the
     Gram matrix ``G = Y^T Y`` (accumulated over row blocks), with the
     traced column selected through masks."""
     m, b = y_ref.shape
     dt = y_ref.dtype
+    chunk = row_block(m)
 
     def gram(c, G):
         yb = y_ref[pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :]
@@ -251,18 +266,9 @@ def t_factor_in_vmem(y_ref, taus, *, chunk: int):
     return jax.lax.fori_loop(0, b, t_step, jnp.zeros((b, b), dt))
 
 
-def _panel_qr_kernel(rs_ref, a_ref, y_ref, t_ref, r_ref, w_ref, *,
-                     chunk: int):
-    m = a_ref.shape[0]
-
-    def load(c, carry):
-        rs = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-        w_ref[rs, :] = a_ref[rs, :]
-        return carry
-
-    jax.lax.fori_loop(0, m // chunk, load, 0)
-    taus, R = householder_in_vmem(w_ref, y_ref, rs_ref[0, 0], chunk=chunk)
-    t_ref[...] = t_factor_in_vmem(y_ref, taus, chunk=chunk)
+def _panel_qr_kernel(rs_ref, a_ref, y_ref, t_ref, r_ref):
+    taus, R = householder_in_vmem(a_ref, y_ref, rs_ref[0, 0])
+    t_ref[...] = t_factor_in_vmem(y_ref, taus)
     r_ref[...] = R
 
 
@@ -280,7 +286,7 @@ def panel_qr_xla(A: jax.Array, row_start: jax.Array, *, unroll: int = 2):
 def panel_qr(A: jax.Array, row_start: jax.Array, *, interpret: bool | None = None):
     """Pallas panel QR. Returns (Y, T, R) like ``ref.panel_qr``.
 
-    A: (m, b) f32, m % 8 == 0 and b % 128 == 0 for full TPU tiling (the
+    A: (m, b) f32, m % 128 == 0 and b % 128 == 0 for full TPU tiling (the
     kernel itself is shape-generic; alignment is a performance contract —
     ``ops.panel_qr`` pads up to it).
     row_start: scalar int32 — rows above it are frozen (CAQR sweep).
@@ -289,10 +295,8 @@ def panel_qr(A: jax.Array, row_start: jax.Array, *, interpret: bool | None = Non
     from repro.kernels import backend
     interpret = backend.resolve_interpret(interpret)
     m, b = A.shape
-    chunk = row_block(m)
     # (1, 1): under vmap the lane axis blocks in front of two whole dims
     rs = jnp.asarray(row_start, jnp.int32).reshape((1, 1))
-    kernel = functools.partial(_panel_qr_kernel, chunk=chunk)
     grid_spec = pl.GridSpec(
         grid=(),
         in_specs=[
@@ -304,10 +308,9 @@ def panel_qr(A: jax.Array, row_start: jax.Array, *, interpret: bool | None = Non
             pl.BlockSpec((b, b), lambda: (0, 0)),
             pl.BlockSpec((b, b), lambda: (0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((m, b), A.dtype)],
     )
     Y, T, R = pl.pallas_call(
-        kernel,
+        _panel_qr_kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((m, b), A.dtype),
@@ -315,7 +318,7 @@ def panel_qr(A: jax.Array, row_start: jax.Array, *, interpret: bool | None = Non
             jax.ShapeDtypeStruct((b, b), A.dtype),
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_limit(5 * m * b * A.dtype.itemsize)),
+            vmem_limit_bytes=vmem_limit(6 * m * b * A.dtype.itemsize)),
         interpret=interpret,
         name="panel_qr",
     )(rs, A)

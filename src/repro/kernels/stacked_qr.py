@@ -28,7 +28,6 @@ from repro.core.householder import MATMUL_PRECISION
 from repro.kernels.panel_qr import (
     householder_in_vmem,
     panel_qr_math,
-    row_block,
     t_factor_in_vmem,
 )
 
@@ -57,10 +56,9 @@ def _stacked_qr_kernel(rt_ref, rb_ref, y2_ref, t_ref, r_ref, s_ref, y_ref,
            <= jax.lax.broadcasted_iota(jnp.int32, (1, b), 1))
     s_ref[:b, :] = jnp.where(tri, rt_ref[...], 0.0)
     s_ref[b:, :] = jnp.where(tri, rb_ref[...], 0.0)
-    chunk = row_block(2 * b)
-    taus, R = householder_in_vmem(s_ref, y_ref, 0, chunk=chunk)
+    taus, R = householder_in_vmem(s_ref, y_ref, 0)
     y2_ref[...] = jnp.where(tri, y_ref[b:, :], 0.0)
-    t_ref[...] = t_factor_in_vmem(y_ref, taus, chunk=chunk)
+    t_ref[...] = t_factor_in_vmem(y_ref, taus)
     r_ref[...] = R
 
 

@@ -44,11 +44,86 @@ def _qr_factor(rng, b, dtype=jnp.float32):
         np.linalg.qr(rng.standard_normal((2 * b, b)))[1], dtype)
 
 
-@pytest.mark.parametrize("m,b", [(32, 8), (64, 16), (256, 32), (128, 128)])
-@pytest.mark.parametrize("row_start", [0, 8])
-def test_panel_qr_sweep(rng, route, m, b, row_start):
+# Row starts by name: mid-tile (off every 8- and 128-row boundary), negative
+# (counts as 0, every row live: a lane whose rows all lie past the panel's
+# diagonal) and the last legal one, m - b.
+_ROW_STARTS = {"mid": lambda m, b: (m - b) // 2 + 3,
+               "neg": lambda m, b: -5,
+               "last": lambda m, b: m - b}
+
+
+@pytest.mark.parametrize("m,b,zero_col", [
+    pytest.param(32, 8, None, id="32-8"),
+    pytest.param(64, 16, None, id="64-16"),
+    pytest.param(256, 32, None, id="256-32"),
+    pytest.param(128, 128, None, id="128-128"),
+    # heights of 3 and 5 lane tiles, one with a degenerate all-zero column
+    pytest.param(384, 128, None, id="384-128"),
+    pytest.param(384, 128, 5, id="384-128-zero5"),
+    pytest.param(640, 128, 70, id="640-128-zero70"),
+])
+@pytest.mark.parametrize("row_start", [0, 8, "mid", "neg", "last"])
+def test_panel_qr_sweep(rng, route, m, b, zero_col, row_start):
     A = jnp.asarray(rng.standard_normal((m, b)), jnp.float32)
-    _allclose(ops.panel_qr(A, row_start), ref.panel_qr(A, row_start))
+    if zero_col is not None:
+        A = A.at[:, zero_col].set(0.0)
+    if row_start in _ROW_STARTS:
+        row_start = _ROW_STARTS[row_start](m, b)
+    _allclose(ops.panel_qr(A, row_start),
+              ref.panel_qr(A, max(row_start, 0)))
+
+
+def test_panel_qr_vmap_is_unbatched_bitwise(rng):
+    """The sweep's vmapped call and the heal's unbatched calls run one
+    kernel body, bit for bit: a healed lane's R equals the failure-free
+    lane's only if they do."""
+    from repro.kernels import panel_qr as _panel
+
+    lanes, m, b = 16, 256, 128
+    A = jnp.asarray(rng.standard_normal((lanes, m, b)), jnp.float32)
+    rs = jnp.asarray(rng.integers(0, m - b + 1, lanes), jnp.int32)
+    call = lambda a, r: _panel.panel_qr(a, r, interpret=True)
+    batched = jax.vmap(call)(A, rs)
+    for i in range(lanes):
+        for got, want in zip(call(A[i], rs[i]), batched):
+            assert np.array_equal(np.asarray(got), np.asarray(want[i]))
+
+
+def test_update_rows_by_tile_height():
+    """The column update's step height is a pure function of the tile's
+    static shape: 32 rows of the benchmark's 15744-row leaf tile, the whole
+    tile for the tree combine's 256 rows and the service's 1024."""
+    from repro.kernels.panel_qr import update_rows
+
+    assert update_rows(15744, 128) == 32
+    assert update_rows(16384, 128) == 32
+    assert update_rows(8192, 128) == 64
+    assert update_rows(1024, 128) == 128
+    assert update_rows(256, 128) == 128
+    assert update_rows(256, 128, sub=16) == 128
+    assert update_rows(16, 8) == 8
+    assert update_rows(40, 12) == 16
+
+
+def test_panel_qr_update_step_changes_no_bit(rng, monkeypatch):
+    """Stepping the update slab by slab, as taller tiles do, gives the
+    same bits as one whole-tile step: the step decides only which finished
+    rows are updated for nothing."""
+    from repro.kernels import panel_qr as _panel
+
+    m, b = 512, 128
+    A = jnp.asarray(rng.standard_normal((m, b)), jnp.float32)
+    rs = jnp.asarray(37, jnp.int32)
+    whole = _panel.panel_qr(A, rs, interpret=True)
+    monkeypatch.setattr(_panel, "_UPDATE_VREGS", 1)
+    assert _panel.update_rows(m, b) == 8
+    jax.clear_caches()
+    try:
+        stepped = _panel.panel_qr(A, rs, interpret=True)
+    finally:
+        jax.clear_caches()
+    for got, want in zip(stepped, whole):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("b", [8, 16, 64, 128])

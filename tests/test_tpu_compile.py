@@ -4,9 +4,10 @@ The TPU compiler is installed with jax, so the Pallas kernels and one sweep
 segment are compiled here at the shapes the production sweep gives them
 (``configs.paper_qr.PRODUCTION``: 8 SimComm lanes of 8192 rows, b = 128,
 trailing windows from 3968 down to 128 columns; 16384 rows per lane on the
-four-chip mesh). What Mosaic or XLA refuses — a dynamic slice it cannot
-lower, a block over the VMEM limit, a program over the HBM — fails here,
-with no chip. Nothing runs: these say nothing about results or times.
+four-chip mesh; for the panel QR also the benchmark's 16 lanes of 15744
+rows). What Mosaic or XLA refuses — a dynamic slice it cannot lower, a
+block over the VMEM limit, a program over the HBM — fails here, with no
+chip. Nothing runs: these say nothing about results or times.
 
 The topology is described inside a module fixture (a worker that cannot
 describe it skips), so importing this file loads no TPU library.
@@ -19,6 +20,8 @@ B = 128
 M_LOC = 8192           # 65536 rows over 8 SimComm lanes
 M_LOC_MESH = 16384     # 65536 rows over a 4-chip lane mesh
 LANES = 8
+M_LOC_CELL = 15744     # the tsqr_tall benchmark: 250000 rows over 16 lanes
+LANES_CELL = 16
 HBM_BYTES = 16 * 2**30
 
 
@@ -54,7 +57,9 @@ def _compile(fn, *args):
 
 
 @pytest.mark.parametrize("m,lanes", [(M_LOC, None), (M_LOC, LANES),
-                                     (M_LOC_MESH, None)])
+                                     (M_LOC_MESH, None),
+                                     (M_LOC_CELL, LANES_CELL),
+                                     (M_LOC_CELL, None)])
 def test_panel_qr_compiles(one_chip, m, lanes):
     from repro.kernels import panel_qr
 
