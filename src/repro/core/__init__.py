@@ -8,7 +8,7 @@ Layers:
   recovery    - failure injection + single-source REBUILD recovery
   comm        - SPMD/simulated communication abstraction
 """
-from repro.core.comm import AxisComm, SimComm
+from repro.core.comm import AxisComm, MeshComm, SimComm
 from repro.core.householder import (
     WY,
     StackedQR,
@@ -60,7 +60,7 @@ from repro.core.caqr import (
 from repro.core import lstsq, recovery
 
 __all__ = [
-    "AxisComm", "SimComm", "WY", "StackedQR", "apply_q", "apply_qt",
+    "AxisComm", "MeshComm", "SimComm", "WY", "StackedQR", "apply_q", "apply_qt",
     "build_t", "householder_qr", "householder_qr_masked", "q_dense",
     "stacked_apply_q", "stacked_apply_qt", "stacked_qr", "ChainFactors",
     "DistTSQRFactors", "baseline_tsqr", "dist_orthonormalize", "ft_tsqr",

@@ -43,7 +43,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.comm import AxisComm, SimComm
+from repro.core.comm import AxisComm
 from repro.core.householder import householder_qr_masked
 from repro.core.tsqr import DistTSQRFactors, ft_tsqr_combine
 from repro.core.trailing import RecoveryBundle, trailing_update_ft
@@ -195,10 +195,10 @@ def assemble_R(comm, R_rows: jax.Array, geom: SweepGeometry) -> jax.Array:
     driver). Rows beyond ``geom.k`` (rank overshoot of a padded or wide
     sweep) and zero-padded columns are sliced away; on aligned geometry both
     slices are no-ops and the assembly is bit-identical to the seed's."""
-    P = comm.axis_size()
     rows = geom.n_panels * geom.b
-    if isinstance(comm, SimComm):
-        R = R_rows.swapaxes(0, 1).reshape(P, rows, geom.n_work)
+    if comm.batched:
+        lanes = R_rows.shape[1]   # P under SimComm, a chip's L under MeshComm
+        R = R_rows.swapaxes(0, 1).reshape(lanes, rows, geom.n_work)
         return jnp.triu(R)[:, :geom.k, :geom.n]
     R = jnp.triu(R_rows.reshape(rows, geom.n_work))
     return R[:geom.k, :geom.n]
@@ -462,7 +462,7 @@ def caqr_apply_qt(
         dist = DistTSQRFactors(
             pf.leaf_Y, pf.leaf_T, pf.level_Y2, pf.level_T, pf.leaf_T
         )
-        tgt = pf.target[0] if isinstance(comm, SimComm) else pf.target
+        tgt = pf.target[0] if comm.batched else pf.target
         B_next, _, _ = trailing_update_ft(
             B_cur, dist, comm, target=tgt, row_start=pf.row_start,
             active=pf.active, dead_threshold=tgt,

@@ -35,7 +35,6 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.comm import SimComm
 from repro.core.householder import apply_qt, mm
 from repro.core.tsqr import DistTSQRFactors, _levels, _xor_perm
 
@@ -159,10 +158,10 @@ def _leaf_apply(comm, factors: DistTSQRFactors, C_local, row_start,
         Cp = jax.lax.dynamic_slice_in_dim(C2, rs, b, axis=0)
         return C2, Cp
 
-    # SimComm's vmap would lower the cond to a select computing BOTH
-    # branches — strictly more work in the simulator, identical results —
-    # so the skip only engages on real SPMD comms.
-    if not skip_consumed or active is None or isinstance(comm, SimComm):
+    # a batched comm's vmap would lower the cond to a select computing BOTH
+    # branches — strictly more work, identical results — so the skip only
+    # engages on comms with one lane per device.
+    if not skip_consumed or active is None or comm.batched:
         return comm.map_local(leaf)(
             factors.leaf_Y, factors.leaf_T, C_local, row_start
         )

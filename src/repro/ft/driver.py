@@ -115,15 +115,24 @@ class RecoveryEvent:
     """One REBUILD: which lane died where, and the single-source read ledger
     (artifact name -> the one surviving lane it was fetched from).
 
-    ``elapsed_s`` is wall-clock REBUILD latency under the eager SimComm path;
-    under shard_map the whole sweep is one traced program, so it records
-    trace time only (use ``benchmarks/bench_spmd.py`` for SPMD REBUILD cost).
+    ``elapsed_s`` is wall-clock REBUILD latency, synced on both sides, under
+    the online orchestrator (SimComm or shard_map heal) and the eager
+    SimComm driver; under the scheduled shard_map path the whole sweep is
+    one traced program, so there it records trace time only (use
+    ``benchmarks/bench_spmd.py`` for that path's REBUILD cost).
+
+    ``xchip_bytes`` is the bytes this heal moved from one chip to another,
+    counted where the heal issues each transfer (the tally of the heal
+    program's comm, ``MeshComm.xchip_bytes``); a heal on one chip moves
+    none. A heal of several
+    lanes counts its bytes once, on its first event.
     """
 
     point: Tuple[int, str, int]
     lane: int
     reads: Dict[str, int]
     elapsed_s: float
+    xchip_bytes: int = 0
 
     @property
     def sources(self) -> List[int]:

@@ -36,8 +36,9 @@ contaminate survivors and then honestly fails the NaN oracle.
 Execution backends: under ``SimComm`` segments are jitted directly; for the
 production SPMD path pass ``step_fn=`` a shard_map segment runner
 (``repro.launch.spmd_qr.make_spmd_sweep_step``) — the state then lives as
-global lane-sharded arrays between segments and all host-side death/REBUILD
-masking runs through the SimComm primitives on the identical global layout.
+global lane-sharded arrays between segments, host-side death masking runs
+through the SimComm primitives on the identical global layout, and the
+REBUILD runs as the runner's own shard_map heal program (``step_fn.heal``).
 """
 from __future__ import annotations
 
@@ -91,7 +92,9 @@ BoundaryHook = Callable[["SweepOrchestrator"], None]
 # detector poll and ``ftqr.heal`` per recovery. ``bench/program_spans.py``
 # splits the device's idle time among them (``idle_share.dispatch``,
 # ``.poll``, ``.loop``, ``.heal``, ``heal_ops``). ``sweep`` ids tie the
-# spans to their factorization.
+# spans to their factorization; ``ftqr.sweep`` names the layout (``chips``,
+# ``lanes_per_chip``), ``ftqr.heal`` the dead lanes' ``chip`` and its
+# ``xchip_reads``, the artifacts it read from another chip.
 _SWEEP_IDS = itertools.count()
 
 
@@ -192,7 +195,8 @@ class SweepOrchestrator:
     step_fn:
         Optional external segment backend, called as ``step_fn(state) ->
         state`` once per sweep point: the SPMD path passes the shard_map
-        runner from ``repro.launch.spmd_qr.make_spmd_sweep_step``.
+        runner from ``repro.launch.spmd_qr.make_spmd_sweep_step``, whose
+        ``heal`` attribute gives the REBUILD program.
     fault_hooks:
         Callables ``hook(comm, state) -> state`` run at every boundary
         *before* the detector poll — test/demo fault injectors
@@ -405,9 +409,13 @@ class SweepOrchestrator:
         instead — epochs at different world sizes have no common lane
         layout for factors, so R is host-spliced."""
         geom = self.state.geom
-        with TraceAnnotation("ftqr.sweep", sweep=next(_SWEEP_IDS),
-                             lanes=self.comm.axis_size(), m_loc=geom.m_loc,
-                             n=geom.n):
+        lanes = self.comm.axis_size()
+        with TraceAnnotation(
+                "ftqr.sweep", sweep=next(_SWEEP_IDS), lanes=lanes,
+                m_loc=geom.m_loc, n=geom.n,
+                chips=getattr(self.step_fn, "chips", 1),
+                lanes_per_chip=getattr(self.step_fn, "lanes_per_chip",
+                                       lanes)):
             if self._resumed:
                 self._resumed = False
                 self._resume_boundary_pass()
@@ -679,47 +687,48 @@ class SweepOrchestrator:
 
     def _heal(self, newly: List[int], point) -> None:
         panel, phase, level = point
+        dead = set(newly)
+        if self.step_fn is None:
+            heal = functools.partial(recover_lanes, self.comm)
+            chips, xchip_reads = "0", 0
+        else:
+            # On the shard_map path the state lives as lane-sharded global
+            # arrays. The heal is one program on the lanes' own devices
+            # (``MeshHeal``): ``recover_lanes`` under the segments' own
+            # comm, so the replay runs the sweep's floating-point program,
+            # on the dead lane's chip block as the segments ran it, and
+            # each buddy artifact moves alone, point to point. That keeps
+            # the healed R bit-identical to the failure-free one. Eager
+            # replay math on the global arrays would compile auto-sharded
+            # executables whose reduction order drifts by ~1 ulp, and
+            # gathering the state onto one device for the heal needs the
+            # whole state in one chip's memory.
+            heal = self.step_fn.heal(self.state, newly, point, self.scheme)
+            chips, xchip_reads = heal.chips, heal.xchip_reads
+
+        def on_recovered(lane: int) -> None:
+            dead.discard(lane)
+            # announce the respawn so the detector re-arms for this lane
+            # immediately (back-to-back deaths at consecutive boundaries
+            # must still be seen)
+            revive = getattr(self.detector, "revive", None)
+            if revive is not None:
+                revive(lane)
+
         with TraceAnnotation("ftqr.heal",
                              lanes=" ".join(str(l) for l in sorted(newly)),
-                             panel=panel, phase=phase, level=level):
-            dead = set(newly)
-            shardings = None
-            if self.step_fn is not None:
-                # The REBUILD replay must be bitwise-identical to the SimComm
-                # oracle, but on the shard_map path the state lives as
-                # lane-sharded global arrays: eager replay math on those
-                # compiles auto-sharded executables whose reduction order
-                # drifts from the single-device programs by ~1 ulp. Gather to
-                # one device for the heal and shard back after — both pure
-                # data movement.
-                shardings = jax.tree_util.tree_map(
-                    lambda x: x.sharding, self.state)
-                dev = jax.devices()[0]
-                self.state = jax.tree_util.tree_map(
-                    lambda x: jax.device_put(x, dev), self.state)
-
-            def on_recovered(lane: int) -> None:
-                dead.discard(lane)
-                # announce the respawn so the detector re-arms for this lane
-                # immediately (back-to-back deaths at consecutive boundaries
-                # must still be seen)
-                revive = getattr(self.detector, "revive", None)
-                if revive is not None:
-                    revive(lane)
-
+                             panel=panel, phase=phase, level=level,
+                             chip=chips, xchip_reads=xchip_reads):
             # the SAME strike-then-rebuild protocol as the scheduled driver's
             # checkpoint — shared code, so the scheduled-vs-online bitwise
             # equivalence cannot drift apart in one copy
-            self.state, events = recover_lanes(
-                self.comm, self.state, newly, point, dead,
+            self.state, events = heal(
+                self.state, newly, point, dead,
                 sync=lambda s: jax.block_until_ready(
                     jax.tree_util.tree_leaves(s)),
                 on_recovered=on_recovered,
                 scheme=self.scheme,
             )
-            if shardings is not None:
-                self.state = jax.tree_util.tree_map(
-                    jax.device_put, self.state, shardings)
             self.events.extend(events)
 
 

@@ -5,9 +5,11 @@ segment are compiled here at the shapes the production sweep gives them
 (``configs.paper_qr.PRODUCTION``: 8 SimComm lanes of 8192 rows, b = 128,
 trailing windows from 3968 down to 128 columns; 16384 rows per lane on the
 four-chip mesh; for the panel QR also the benchmark's 16 lanes of 15744
-rows). What Mosaic or XLA refuses — a dynamic slice it cannot lower, a
-block over the VMEM limit, a program over the HBM — fails here, with no
-chip. Nothing runs: these say nothing about results or times.
+rows; the ``tsqr_mesh4`` cell's 4 lanes of 31360 rows per chip, its sweep
+segments and its heal on the described 2x2 mesh). What Mosaic or XLA
+refuses — a dynamic slice it cannot lower, a block over the VMEM limit, a
+program over the HBM — fails here, with no chip. Nothing runs: these say
+nothing about results or times.
 
 The topology is described inside a module fixture (a worker that cannot
 describe it skips), so importing this file loads no TPU library.
@@ -22,6 +24,9 @@ M_LOC_MESH = 16384     # 65536 rows over a 4-chip lane mesh
 LANES = 8
 M_LOC_CELL = 15744     # the tsqr_tall benchmark: 250000 rows over 16 lanes
 LANES_CELL = 16
+M_MESH4, N_MESH4 = 500000, 1000   # the tsqr_mesh4 cell: 16 lanes, 4 per chip
+LANES_MESH4, CHIPS_MESH4 = 16, 4
+M_LOC_MESH4 = 31360    # its 31250-row blocks padded to the panel
 HBM_BYTES = 16 * 2**30
 
 
@@ -56,6 +61,14 @@ def _compile(fn, *args):
     return compiled
 
 
+def _device_bytes(compiled) -> int:
+    """Bytes one device holds for a program: arguments, outputs and
+    temporaries (per device for a partitioned program)."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+
+
 @pytest.mark.parametrize("m,lanes", [(M_LOC, None), (M_LOC, LANES),
                                      (M_LOC_MESH, None),
                                      (M_LOC_CELL, LANES_CELL),
@@ -68,6 +81,18 @@ def test_panel_qr_compiles(one_chip, m, lanes):
     if lanes:
         fn, a, rs = jax.vmap(fn), (lanes,) + a, (lanes,)
     _compile(fn, _shape(one_chip, a), _shape(one_chip, rs, jnp.int32))
+
+
+def test_panel_qr_compiles_mesh4_leaf(one_chip):
+    """The tsqr_mesh4 leaf: one chip's 4 lanes of 31360 rows, vmapped; its
+    6 * m * b words (91.9 MiB) sit just under the 100 MiB VMEM cap."""
+    from repro.kernels import panel_qr
+
+    fn = jax.vmap(lambda a, rs: panel_qr.panel_qr(a, rs, interpret=False))
+    compiled = _compile(
+        fn, _shape(one_chip, (LANES_MESH4 // CHIPS_MESH4, M_LOC_MESH4, B)),
+        _shape(one_chip, (LANES_MESH4 // CHIPS_MESH4,), jnp.int32))
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 @pytest.mark.parametrize("lanes", [None, LANES])
@@ -128,3 +153,84 @@ def test_sweep_segment_compiles(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
+
+
+@pytest.fixture(scope="module")
+def mesh4(one_chip):
+    """The described 2x2 host as a 1-D mesh of its four chips, and the
+    tsqr_mesh4 state's abstract entry layout (SimComm(16) global arrays,
+    lane axes sharded over the chips)."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices[:CHIPS_MESH4]), ("qr",))
+
+
+def _mesh4_state(mesh, cursor):
+    """The tsqr_mesh4 sweep state at ``cursor``, abstract, sharded as the
+    online SPMD path holds it between segments."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.comm import SimComm
+    from repro.ft.online.state import (initial_sweep_state, run_steps,
+                                       state_lane_axes)
+
+    comm = SimComm(LANES_MESH4)
+    state = jax.eval_shape(
+        lambda a: initial_sweep_state(comm, a, B),
+        jax.ShapeDtypeStruct(
+            (LANES_MESH4, M_MESH4 // LANES_MESH4, N_MESH4), jnp.float32))
+    while state.cursor != cursor:
+        state = jax.eval_shape(lambda s: run_steps(comm, s, 1), state)
+
+    def spec(ax):
+        return P() if ax < 0 else P(*([None] * ax + ["qr"]))
+
+    return jax.tree_util.tree_map(
+        lambda x, ax: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec(ax))),
+        state, state_lane_axes(state))
+
+
+@pytest.mark.parametrize("program,level", [("segment", 0), ("segment", 2),
+                                           ("heal", None)])
+def test_mesh4_program_compiles(mesh4, monkeypatch, program, level):
+    """The tsqr_mesh4 cell's programs for a described 2x2 v5e: trailing
+    combine points of panel 0 as MeshComm segments, 4 lanes per chip —
+    level 0 (with the leaf apply, the widest trailing update) pairs lanes
+    of one chip and moves nothing across chips, level 2 pairs chips by a
+    collective-permute — and the heal of lane 3 after panel 4's first
+    trailing level (the cell's kill), which fetches from lanes 7 and 11 on
+    other chips. Each is partitioned four ways and each chip's share fits
+    its HBM."""
+    from repro.dist import compat
+    from repro.ft.coding import XORPairScheme
+    from repro.ft.failures import sweep_point
+    from repro.kernels import backend
+    from repro.launch.spmd_qr import make_spmd_sweep_step
+
+    monkeypatch.setattr(backend, "platform", lambda: "tpu")
+    step = make_spmd_sweep_step(mesh4, lanes_per_chip=LANES_MESH4
+                                // CHIPS_MESH4)
+    if program == "segment":
+        state = _mesh4_state(mesh4, sweep_point(0, "trailing", level))
+        jitted = step.program(state)
+        crosses = level >= 2
+    else:
+        point = sweep_point(4, "trailing", 0)
+        state = _mesh4_state(mesh4, sweep_point(4, "trailing", 1))
+        heal = step.heal(state, [3], point, XORPairScheme())
+        assert sorted(set(heal.reads[3].values())) == [1, 2, 7, 11]
+        assert 0 < heal.xchip_bytes < 2**30 // 8
+        jitted, crosses = heal.program, True
+    with compat.set_mesh(mesh4):
+        compiled = jitted.lower(state).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"num_partitions={CHIPS_MESH4}" in text
+    assert ("collective-permute" in text) == crosses
+    assert _device_bytes(compiled) < HBM_BYTES, (
+        f"{_device_bytes(compiled) / 2**30:.2f} GiB")
