@@ -5,16 +5,12 @@ training step, and what the async double-buffered segment path buys back.
     a detector poll plus segment dispatch. With ``async_segments=True`` the
     orchestrator dispatches the NEXT segment speculatively before the
     boundary's poll result arrives, overlapping dispatch with detection;
-    the non-blocking probe collapses the poll itself to one compiled
-    dispatch. Measured as per-boundary wall time over a full
+    the poll itself is one compiled probe dispatch. Measured as
+    per-boundary wall time over a full
     ``orthonormalize`` sweep, interleaved sync/async so box drift cancels.
     The gate demands async strictly cheaper than sync per boundary.
 
-(b) *Poll cost, eager vs probe*: the eager ``NaNSentinelDetector.poll``
-    (one host sync per per-lane sentinel read) vs the compiled
-    ``probe``/``collect`` pair (a single fused reduction dispatch).
-
-(c) *Step cost, free vs killed*: an FT training step whose optimizer-
+(b) *Step cost, free vs killed*: an FT training step whose optimizer-
     internal sweep loses a lane pays one REBUILD; measured as the killed
     step's wall time against the same step of a failure-free run.
 
@@ -36,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 # gated ratios may regress this much over the recorded baseline before CI
-# fails (the async-vs-sync and probe-vs-poll gates are intra-run and
-# absolute: async/probe must simply win)
+# fails (the async-vs-sync gate is intra-run and absolute: async must
+# simply win)
 REGRESSION_TOLERANCE = 1.25
 # measurement methodology version: bump when the meaning of a gated number
 # changes, so the gate re-records instead of comparing incomparables
@@ -94,36 +90,8 @@ def bench_boundary_cost(quick: bool = False) -> Dict:
     }
 
 
-def bench_poll_cost(quick: bool = False) -> Dict:
-    """(b): one detector check, eager poll vs compiled probe/collect."""
-    from repro.core import SimComm
-    from repro.core.caqr import block_row_layout
-    from repro.ft.online.detect import NaNSentinelDetector
-    from repro.ft.online.state import initial_sweep_state
-
-    P, pw, (m, n) = (4, 16, (128, 64)) if quick else (8, 16, (256, 128))
-    comm = SimComm(P)
-    rng = np.random.default_rng(32)
-    M = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
-    st = initial_sweep_state(comm, block_row_layout(M, P), pw)
-    reps = 20 if quick else 50
-
-    det_poll, det_probe = NaNSentinelDetector(), NaNSentinelDetector()
-    det_poll.poll(comm, st)                                    # warm
-    det_probe.collect(comm, det_probe.probe(comm, st))         # compile
-    us_poll = _wall(lambda: det_poll.poll(comm, st), reps)
-    us_probe = _wall(
-        lambda: det_probe.collect(comm, det_probe.probe(comm, st)), reps)
-    return {
-        "config": {"P": P, "panel_width": pw, "m": m, "n": n, "quick": quick},
-        "us_poll_eager": us_poll,
-        "us_poll_probe": us_probe,
-        "probe_vs_poll": us_probe / max(us_poll, 1e-9),
-    }
-
-
 def bench_step_cost(quick: bool = False) -> Dict:
-    """(c): FT training step wall time, failure-free vs a lane killed
+    """(b): FT training step wall time, failure-free vs a lane killed
     inside the step's optimizer-internal sweep (one REBUILD)."""
     from repro.configs import get_smoke
     from repro.data.pipeline import DataConfig
@@ -165,45 +133,38 @@ def bench_step_cost(quick: bool = False) -> Dict:
 def suite(quick: bool = False) -> Dict:
     return {
         "boundary": bench_boundary_cost(quick),
-        "poll": bench_poll_cost(quick),
         "step": bench_step_cost(quick),
     }
 
 
 def check_regression(train: Dict, baseline: Optional[Dict]) -> Tuple[bool, str]:
-    """Gate for ``run.py``/``ci.sh``. Two intra-run absolutes — the async
-    double-buffered path must be strictly cheaper per boundary than sync,
-    and the compiled probe must beat the eager poll — plus a baseline gate
-    on the per-boundary sync cost (same quick-tier only).
+    """Gate for ``run.py``/``ci.sh``. One intra-run absolute — the async
+    double-buffered path must be strictly cheaper per boundary than sync —
+    plus a baseline gate on the per-boundary sync cost (same quick-tier
+    only).
     ``CI_ALLOW_TRAIN_REGRESSION=1`` acknowledges a failure without
     greening it."""
     allow = os.environ.get("CI_ALLOW_TRAIN_REGRESSION") == "1"
     av = train["boundary"]["async_vs_sync"]
-    pv = train["poll"]["probe_vs_poll"]
     if av >= 1.0:
         msg = (f"async segments are NOT cheaper than sync per boundary "
                f"({av:.2f}x, must be < 1.0)")
         return (True, msg + " — acknowledged via CI_ALLOW_TRAIN_REGRESSION=1"
                 ) if allow else (False, msg)
-    if pv >= 1.0:
-        msg = (f"compiled probe is NOT cheaper than the eager poll "
-               f"({pv:.2f}x, must be < 1.0)")
-        return (True, msg + " — acknowledged via CI_ALLOW_TRAIN_REGRESSION=1"
-                ) if allow else (False, msg)
     got = train["boundary"]["us_sync_per_boundary"]
     if not baseline:
-        return True, (f"train async {av:.2f}x, probe {pv:.2f}x, boundary "
+        return True, (f"train async {av:.2f}x, boundary "
                       f"{got:.0f}us (no baseline recorded yet)")
     base_b = baseline.get("boundary", {})
     comparable = (base_b.get("config", {}).get("quick")
                   == train["boundary"]["config"]["quick"]
                   and base_b.get("method") == train["boundary"]["method"])
     if not comparable:
-        return True, (f"train async {av:.2f}x, probe {pv:.2f}x (baseline "
+        return True, (f"train async {av:.2f}x (baseline "
                       "from the other tier/method; not comparable)")
     base = base_b["us_sync_per_boundary"]
     if got <= base * REGRESSION_TOLERANCE:
-        return True, (f"train async {av:.2f}x, probe {pv:.2f}x, boundary "
+        return True, (f"train async {av:.2f}x, boundary "
                       f"{got:.0f}us vs baseline {base:.0f}us: OK")
     msg = (f"train per-boundary cost REGRESSED: {got:.0f}us vs baseline "
            f"{base:.0f}us (> {REGRESSION_TOLERANCE:.2f}x tolerance)")

@@ -181,15 +181,13 @@ def main() -> None:
     from benchmarks import bench_train
 
     train = bench_train.suite(quick=args.quick)
-    bd, pl, stp = train["boundary"], train["poll"], train["step"]
+    bd, stp = train["boundary"], train["step"]
     print()
     print("# train path: optimizer-internal FT-QR inside the training step")
     print(f"# boundary ({bd['config']['boundaries']} per sweep): "
           f"sync {bd['us_sync_per_boundary']:.0f}us, "
           f"async {bd['us_async_per_boundary']:.0f}us "
-          f"({bd['async_vs_sync']:.2f}x); poll: eager "
-          f"{pl['us_poll_eager']:.0f}us, probe {pl['us_poll_probe']:.0f}us "
-          f"({pl['probe_vs_poll']:.2f}x)")
+          f"({bd['async_vs_sync']:.2f}x)")
     print(f"# step: free {stp['us_step_free']/1e3:.0f}ms, killed "
           f"{stp['us_step_killed']/1e3:.0f}ms "
           f"(REBUILD adds {stp['us_rebuild_delta']/1e3:.0f}ms, "

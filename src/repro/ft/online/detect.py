@@ -12,13 +12,13 @@ Detectors (the ``OnlineDetector`` protocol):
 
 * ``NaNSentinelDetector`` — probes sentinel slots of the lane-sharded state
   between segments (element ``[0, 0]`` of each lane's block-row slice, plus
-  the in-flight R/C' heads). O(P) scalars transferred per poll; a ``deep``
-  mode scans every float leaf for hardening/debugging. Latency bound: a
-  death is reported at the first boundary after it happens — one segment.
-  Also exposes the split non-blocking form ``probe``/``collect``: ``probe``
-  dispatches ONE compiled sentinel reduction and returns a handle,
-  ``collect`` materializes it — the async orchestrator dispatches the next
-  segment between the two, hiding the transfer behind device work.
+  the in-flight R/C' heads): ONE compiled program per poll and O(P)
+  scalars transferred; a ``deep`` mode scans every float leaf for
+  hardening/debugging. Latency bound: a death is reported at the first
+  boundary after it happens — one segment. ``poll`` is the split form
+  ``collect(probe(...))``: ``probe`` dispatches the program and returns a
+  handle, ``collect`` materializes it, so a caller may dispatch device work
+  between the two.
 * ``FailStopDetector`` — injectable test double: the harness ``declare``-s a
   death and the detector reports it after ``report_delay`` polls (0 = the
   very next boundary; 1 = one segment late, the false-negative case).
@@ -35,8 +35,10 @@ detector.
 """
 from __future__ import annotations
 
+import functools
+import operator
 import time
-from typing import Any, Callable, Dict, Iterable, List, Optional, Protocol, \
+from typing import Any, Dict, Iterable, List, Optional, Protocol, \
     Set, Tuple
 
 import jax
@@ -64,43 +66,29 @@ class OnlineDetector(Protocol):
         would go unreported."""
 
 
-def _sentinel_values(comm, state: SweepState) -> np.ndarray:
-    """One float per lane: the sum of this lane's sentinel slots (NaN iff
-    any probe slot is NaN). Probes the block-row head plus whatever
-    in-flight per-lane artifact heads exist at the current cursor."""
-    probes = []
-    for field in ("A", "window", "R_leaf", "R_carry", "C_prime"):
-        x = getattr(state, field)
-        if x is not None:
-            # the head element of each lane's slice, read in place (a
-            # flattening reshape would copy the whole array on TPU)
-            probes.append(x[(slice(None),) + (0,) * (x.ndim - 1)])
-    return np.asarray(jnp.sum(jnp.stack(probes), axis=0))
+# The probed fields: the block row plus the in-flight per-lane artifacts,
+# each with its lane axis first.
+_PROBED_FIELDS = ("A", "window", "R_leaf", "R_carry", "C_prime")
 
 
-# One jitted sentinel reduction per lane count; jax's cache specializes per
-# state treedef (= per cursor), exactly like the orchestrator's segments.
-_SENTINEL_FNS: Dict[int, Callable] = {}
+def _probed(state: SweepState) -> Tuple:
+    """The probed fields present at the state's cursor, in a fixed order."""
+    return tuple(x for x in (getattr(state, f) for f in _PROBED_FIELDS)
+                 if x is not None)
 
 
-def _sentinel_program(P: int) -> Callable:
-    """Compiled form of ``_sentinel_values``: the whole probe (reshape +
-    head-gather + sum) is ONE dispatch returning a length-``P`` device
-    array, instead of ~7 eager ops per poll. The caller decides when to
-    materialize it — that split is what makes the probe non-blocking."""
-    fn = _SENTINEL_FNS.get(P)
-    if fn is None:
-        def sent(state: SweepState):
-            probes = []
-            for field in ("A", "window", "R_leaf", "R_carry", "C_prime"):
-                x = getattr(state, field)
-                if x is not None:
-                    probes.append(x.reshape(P, -1)[:, 0])
-            return jnp.sum(jnp.stack(probes), axis=0)
+@jax.jit
+def _sentinel_probe(fields: Tuple) -> jax.Array:
+    """One float per lane: the sum of the head element of each lane's slice
+    of every probed field (NaN iff any head is NaN), as ONE compiled program
+    returning a length-P device array.
 
-        fn = jax.jit(sent)
-        _SENTINEL_FNS[P] = fn
-    return fn
+    The heads are read in place: a flattening reshape would copy the whole
+    field on TPU's tiled layout. The program is keyed on the fields' shapes
+    alone, not on the whole state, so the sweep's cursors share far fewer
+    specializations than they have points."""
+    heads = [x[(slice(None),) + (0,) * (x.ndim - 1)] for x in fields]
+    return functools.reduce(operator.add, heads)
 
 
 def _deep_nan_lanes(comm, state: SweepState) -> Set[int]:
@@ -109,8 +97,6 @@ def _deep_nan_lanes(comm, state: SweepState) -> Set[int]:
     P = comm.axis_size()
     hit: Set[int] = set()
     axes = state_lane_axes(state)
-    import jax
-
     for x, ax in zip(jax.tree_util.tree_leaves(state),
                      jax.tree_util.tree_leaves(axes)):
         if not jnp.issubdtype(x.dtype, jnp.floating):
@@ -141,27 +127,21 @@ class NaNSentinelDetector:
         self._reported: Set[int] = set()
 
     def poll(self, comm, state: SweepState) -> List[int]:
-        if self.deep:
-            hit = _deep_nan_lanes(comm, state)
-        else:
-            hit = {int(i)
-                   for i in np.flatnonzero(np.isnan(_sentinel_values(comm, state)))}
-        newly = sorted(hit - self._reported)
-        self._reported = hit  # healed lanes re-arm automatically
-        return newly
+        """The lanes newly dead at this boundary: one probe dispatch and one
+        transfer of P floats (``deep``: the full scan)."""
+        return self.collect(comm, self.probe(comm, state))
 
     # -- non-blocking probe (the async orchestrator's poll) -----------------
 
     def probe(self, comm, state: SweepState) -> Any:
         """Dispatch the sentinel reduction WITHOUT materializing it and
         return an opaque handle for :meth:`collect`. Under jax's async
-        dispatch the reduction runs while the host does other work (the
-        async orchestrator dispatches the next segment in between) — the
+        dispatch the reduction runs while the host does other work — the
         blocking transfer is deferred to ``collect``. ``deep`` mode has no
         compiled form; its handle just defers the full scan."""
         if self.deep:
             return ("deep", state)
-        return ("sent", _sentinel_program(comm.axis_size())(state))
+        return ("sent", _sentinel_probe(_probed(state)))
 
     def collect(self, comm, handle: Any) -> List[int]:
         """Materialize a :meth:`probe` handle into the newly-dead list —

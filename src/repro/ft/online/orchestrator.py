@@ -248,11 +248,11 @@ class SweepOrchestrator:
         poisons the lane and escalates to a SHRINK transition.
     async_segments:
         Double-buffered segment execution: dispatch segment N+1 before
-        collecting the detector probe on segment N's boundary (the probe
-        itself is the split non-blocking ``probe``/``collect`` form when
-        the detector has one). Results are bitwise-identical to the sync
-        loop — a fault-hook mutation or a detected death discards the
-        in-flight speculation and re-dispatches from the recovered state.
+        polling the detector on segment N's boundary. Results are
+        bitwise-identical to the sync loop — a fault-hook mutation or a
+        detected death discards the in-flight speculation and re-dispatches
+        from the recovered state
+        (``tests/test_online_recovery.py::test_async_matches_sync``).
         REBUILD/ABORT semantics only (no elastic/straggler/fused
         composition). ``benchmarks/bench_train.py`` gates async strictly
         cheaper per boundary than sync.
@@ -494,21 +494,11 @@ class SweepOrchestrator:
         if newly:
             self._recover(newly, point)
 
-    def _poll(self, state: SweepState, boundary: int, split: bool = False
-              ) -> List[int]:
-        """One detector poll, under its ``ftqr.poll`` span. ``split`` (the
-        async loop) takes the split ``probe``/``collect`` form when the
-        detector has it (``NaNSentinelDetector``): the caller dispatches
-        device work between probe dispatch and collect. Plain ``poll`` is
-        the fallback for protocol-only detectors."""
+    def _poll(self, state: SweepState, boundary: int) -> List[int]:
+        """One detector poll, under its ``ftqr.poll`` span."""
         with TraceAnnotation("ftqr.poll", boundary=boundary):
             t0 = time.perf_counter()
-            probe = getattr(self.detector, "probe", None) if split else None
-            if probe is None:
-                newly = list(self.detector.poll(self.comm, state))
-            else:
-                newly = list(self.detector.collect(self.comm,
-                                                   probe(self.comm, state)))
+            newly = list(self.detector.poll(self.comm, state))
             self.poll_s += time.perf_counter() - t0
         return newly
 
@@ -525,8 +515,8 @@ class SweepOrchestrator:
         same state when they do nothing) or a detected death discards it
         and re-dispatches from the authoritative recovered state, which is
         exactly what the sync loop would have run — results stay bitwise
-        identical to sync execution (``tests/test_online_recovery.py``
-        gates this differentially)."""
+        identical to sync execution
+        (``tests/test_online_recovery.py::test_async_matches_sync``)."""
         boundary = 0
         cur = self.state
         if cur.cursor is not None:
@@ -548,7 +538,7 @@ class SweepOrchestrator:
                 spec = self._segment(cur)
             boundary += 1
             self.boundaries += 1
-            newly = self._poll(cur, boundary, split=True)
+            newly = self._poll(cur, boundary)
             self.state = cur
             if newly:
                 spec = None  # speculated from a state recovery rewrites

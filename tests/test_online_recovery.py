@@ -310,6 +310,116 @@ def test_nan_sentinel_deep_scan(ragged_reference):
     assert [(e.point, e.lane) for e in got.events] == [(point, 1)]
 
 
+# -- the compiled sentinel probe ---------------------------------------------
+
+# SimComm(16) (four butterfly levels) at a tiny size: 3 panels
+PROBE_P, PROBE_M_LOC, PROBE_N, PROBE_B = 16, 8, 12, 4
+PROBE_POINTS = [sweep_point(1, "leaf"), sweep_point(1, "tsqr", 0),
+                sweep_point(1, "tsqr", 3), sweep_point(1, "trailing", 0),
+                sweep_point(1, "trailing", 3)]
+
+
+@pytest.fixture(scope="module")
+def probe_states():
+    """The healthy SimComm(16) state at the boundary after each of
+    ``PROBE_POINTS``."""
+    from repro.ft.online.orchestrator import compiled_segment
+
+    comm = SimComm(PROBE_P)
+    s = initial_sweep_state(
+        comm, _matrix(PROBE_P, PROBE_M_LOC, PROBE_N, seed=11), PROBE_B)
+    seg = compiled_segment(comm, 1)
+    states = {}
+    while s.cursor is not None:
+        s = seg(s)
+        point = prev_sweep_point(s.cursor, s.geom.n_panels, s.geom.levels)
+        if point in PROBE_POINTS:
+            states[point] = s
+    assert set(states) == set(PROBE_POINTS)
+    return comm, states
+
+
+@pytest.mark.parametrize("lane", [0, 6, 15])
+@pytest.mark.parametrize("point", PROBE_POINTS,
+                         ids=lambda p: f"p{p[0]}-{p[1]}{p[2]}")
+def test_sentinel_probe_matches_deep_scan(probe_states, point, lane):
+    """At a leaf, TSQR and trailing boundary, the compiled sentinel probe
+    reports exactly the lanes the full scan reports: none on the healthy
+    state, the dead lane once after ``obliterate_state``, and the same lane
+    again after ``revive`` re-arms it."""
+    from repro.ft.driver import obliterate_state
+
+    comm, states = probe_states
+    healthy = states[point]
+    dead = obliterate_state(comm, healthy, lane)
+    deep = NaNSentinelDetector(deep=True)
+    assert deep.poll(comm, healthy) == []
+    assert deep.poll(comm, dead) == [lane]
+    det = NaNSentinelDetector()
+    assert det.poll(comm, healthy) == []
+    assert det.poll(comm, dead) == [lane]
+    assert det.poll(comm, dead) == []  # reported once per death
+    det.revive(lane)
+    assert det.poll(comm, dead) == [lane]
+    assert det.poll(comm, healthy) == []
+    assert det.poll(comm, dead) == [lane]  # healed lanes re-arm by themselves
+
+
+def test_sentinel_probe_programs():
+    """Over one sweep the probe compiles once per distinct signature of the
+    probed fields — fewer programs than boundaries, each built while the
+    first sweep runs — and no program reshapes or materializes a field:
+    every value it computes holds at most one float per lane."""
+    from repro.ft.online.detect import _probed, _sentinel_probe
+
+    seen = {}
+
+    def record(orch):
+        fields = _probed(orch.state)
+        seen.setdefault(tuple((x.shape, x.dtype) for x in fields), fields)
+
+    A = _matrix(PROBE_P, PROBE_M_LOC, PROBE_N, seed=12)
+    _sentinel_probe.clear_cache()
+    orch = SweepOrchestrator(A, SimComm(PROBE_P), PROBE_B,
+                             boundary_hooks=[record])
+    orch.run()
+    assert 0 < _sentinel_probe._cache_size() <= len(seen) < orch.boundaries
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for param in eqn.params.values():
+                sub = getattr(param, "jaxpr", param)  # a closed jaxpr's body
+                if hasattr(sub, "eqns"):
+                    yield from eqns(sub)
+
+    for fields in seen.values():
+        closed = jax.make_jaxpr(_sentinel_probe)(fields)
+        for eqn in eqns(closed.jaxpr):
+            assert eqn.primitive.name != "reshape", eqn
+            for v in eqn.outvars:
+                assert np.prod(v.aval.shape) <= PROBE_P, eqn
+    # a later sweep over the same geometry builds nothing
+    SweepOrchestrator(A, SimComm(PROBE_P), PROBE_B).run()
+    assert _sentinel_probe._cache_size() <= len(seen)
+
+
+def test_async_matches_sync():
+    """The double-buffered loop against the sync loop on SimComm(8) with a
+    kill after a trailing level: bit-identical R, factors and bundles, and
+    the same recovery events (the kill discards the speculative segment)."""
+    A = _matrix(8, 8, 16, seed=13)
+    point = sweep_point(1, "trailing", 1)
+    got = {}
+    for async_segments in (False, True):
+        got[async_segments] = ft_caqr_sweep_online(
+            A, SimComm(8), 4, async_segments=async_segments,
+            fault_hooks=[ScriptedKiller({point: [5]})])
+    _assert_bit_identical(got[True], got[False])
+    _assert_same_events(got[True], got[False])
+    assert [(e.point, e.lane) for e in got[True].events] == [(point, 5)]
+
+
 def test_segmented_execution_and_boundary_kill(ragged_reference):
     """segment_points > 1: fewer boundaries, same bits; a kill at a segment
     boundary recovers exactly like the scheduled oracle."""

@@ -234,3 +234,39 @@ def test_mesh4_program_compiles(mesh4, monkeypatch, program, level):
     assert ("collective-permute" in text) == crosses
     assert _device_bytes(compiled) < HBM_BYTES, (
         f"{_device_bytes(compiled) / 2**30:.2f} GiB")
+
+
+@pytest.mark.parametrize("where", ["one_chip", "mesh4"])
+def test_sentinel_probe_compiles(one_chip, mesh4, where):
+    """The detector's sentinel probe at a benchmark cell's first trailing
+    boundary (the two 1024-column fields of 15744 or 31360 rows per lane),
+    for a described v5e and for the described 2x2 mesh: ONE fusion reading
+    each lane's head elements in place — no copy of a field, no
+    temporaries, no collective — returning one float per lane."""
+    from repro.core.comm import SimComm
+    from repro.ft.failures import sweep_point
+    from repro.ft.online.detect import _probed, _sentinel_probe
+    from repro.ft.online.state import initial_sweep_state, run_steps
+
+    point = sweep_point(0, "trailing", 0)
+    if where == "mesh4":
+        state = _mesh4_state(mesh4, point)
+    else:
+        comm = SimComm(LANES_CELL)
+        state = jax.eval_shape(
+            lambda a: initial_sweep_state(comm, a, B),
+            jax.ShapeDtypeStruct((LANES_CELL, M_LOC_CELL, N_MESH4),
+                                 jnp.float32))
+        while state.cursor != point:
+            state = jax.eval_shape(lambda s: run_steps(comm, s, 1), state)
+        state = jax.tree_util.tree_map(
+            lambda x: _shape(one_chip, x.shape, x.dtype), state)
+    fields = _probed(state)
+    assert max(x.size for x in fields) >= LANES_CELL * M_LOC_CELL * 1024
+    compiled = _sentinel_probe.lower(fields).compile()
+    text = compiled.as_text()
+    assert text.count(" fusion(") == 1
+    for op in (" copy(", "all-gather", "all-reduce", "collective-permute"):
+        assert op not in text, op
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    assert compiled.out_info.shape == (LANES_CELL,)

@@ -129,8 +129,8 @@ echo "== online stepped overhead, the elastic SHRINK continuation, the =="
 echo "== serve continuous-batching overhead, the coded-lane f=2 encode =="
 echo "== overhead, or the train per-boundary cost regresses >25% over =="
 echo "== the recorded baseline — and the train tier's async segments =="
-echo "== and compiled probe must be strictly cheaper than their sync =="
-echo "== counterparts; escapes: CI_ALLOW_ONLINE_REGRESSION=1 / =="
+echo "== must be strictly cheaper than sync; escapes: =="
+echo "== CI_ALLOW_ONLINE_REGRESSION=1 / =="
 echo "== CI_ALLOW_ELASTIC_REGRESSION=1 / CI_ALLOW_SERVE_REGRESSION=1 / =="
 echo "== CI_ALLOW_CODING_REGRESSION=1 / CI_ALLOW_TRAIN_REGRESSION=1) =="
 python -m benchmarks.run --quick
