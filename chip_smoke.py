@@ -116,9 +116,8 @@ def kernel_report() -> dict:
     check(backend.dispatch_enabled(),
           "the core -> kernel dispatch is off on this device")
     for op, engine in engines.items():
-        want = (backend.ENGINE_PALLAS if op in backend.PALLAS_ON_TPU
-                else backend.ENGINE_XLA)
-        check(engine == want, f"{op} runs {engine}, the policy says {want}")
+        check(engine == backend.ENGINE_PALLAS,
+              f"{op} runs {engine}, the policy says pallas")
     return {"platform": dev.platform, "kind": dev.device_kind,
             "count": len(jax.devices()), "engines": engines,
             "matmul_precision": str(MATMUL_PRECISION)}

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke
-from repro.core import SimComm, caqr_factorize
+from repro.core import SimComm
 from repro.data.pipeline import DataConfig
 from repro.ft import FailureSchedule, ft_caqr_sweep, sweep_point
 from repro.ft.semantics import Semantics
@@ -40,7 +40,7 @@ A = jnp.asarray(rng.standard_normal((P, m_loc, n)), jnp.float32)
 comm = SimComm(P)
 
 print(f"=== FT-CAQR sweep: {P*m_loc}x{n}, {n//b} panels, {P} lanes ===")
-ref = caqr_factorize(A, comm, b, collect_bundles=True, use_scan=False)
+ref = ft_caqr_sweep(A, comm, b)  # the failure-free run
 schedule = FailureSchedule(events={
     sweep_point(2, "trailing", 1): [2],  # mid trailing-combine tree
     sweep_point(5, "tsqr", 0): [1],      # mid TSQR butterfly, later panel

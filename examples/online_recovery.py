@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt import load_sweep_state, save_sweep_state
-from repro.core import SimComm, caqr_factorize
+from repro.core import SimComm
 from repro.ft import SweepOrchestrator
 from repro.ft.online.detect import NaNSentinelDetector, WallClockKiller
 from repro.ft.online.state import initial_sweep_state, sweep_step
@@ -46,7 +46,7 @@ comm = SimComm(P)
 
 print(f"=== online FT-CAQR: {P*m_loc}x{n}, {n//b} panels, {P} lanes, "
       f"kill lane 2 after ~{args.after_ms:.0f}ms of wall clock ===")
-ref = caqr_factorize(A, comm, b, collect_bundles=True, use_scan=False)
+ref = SweepOrchestrator(A, comm, b).run()  # the failure-free run
 
 killer = WallClockKiller(after_s=args.after_ms / 1e3, lane=2)
 orch = SweepOrchestrator(A, comm, b, detector=NaNSentinelDetector(),

@@ -37,8 +37,7 @@ from repro.ft import FailureSchedule, ft_caqr_sweep, sweep_point
 
 n_small = 64  # 4 panels, 3 tree levels
 A_small = A[:, :, :n_small]
-ref = caqr_factorize(A_small, SimComm(P), panel_width=b, use_scan=False,
-                     collect_bundles=True)
+ref = ft_caqr_sweep(A_small, SimComm(P), panel_width=b)
 schedule = FailureSchedule(events={
     sweep_point(1, "trailing", 2): [3],   # lane 3 dies mid trailing tree
     sweep_point(3, "tsqr", 0): [5],       # lane 5 dies mid TSQR, last panel

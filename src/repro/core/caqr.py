@@ -27,6 +27,10 @@ remaining columns ride along in every trailing update and finish as the
 and run the exact seed code path (bit-identical — regression-gated by
 ``tests/test_general_shapes.py``).
 
+The sweep itself — leaf QR, butterfly levels and the windowed trailing
+update of every panel — is the state machine of ``repro.ft.online.state``;
+``caqr_factorize`` compiles it, run to completion, as one program.
+
 Because row permutations do not change the R factor, the final R here equals
 (up to row signs) the R of any standard QR — validated against
 ``jnp.linalg.qr`` and via the Gram identity ``R^T R == A^T A``.
@@ -38,14 +42,13 @@ and by least-squares solves).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.comm import AxisComm
-from repro.core.householder import householder_qr_masked
-from repro.core.tsqr import DistTSQRFactors, ft_tsqr_combine
+from repro.core.tsqr import DistTSQRFactors
 from repro.core.trailing import RecoveryBundle, trailing_update_ft
 
 
@@ -204,237 +207,53 @@ def assemble_R(comm, R_rows: jax.Array, geom: SweepGeometry) -> jax.Array:
     return R[:geom.k, :geom.n]
 
 
-def advance_columns(comm, A_cur: jax.Array, window_next: jax.Array, col0: int):
-    """Reattach the updated live window to the (untouched) dead columns."""
-    return comm.map_local(
-        lambda A, W: jnp.concatenate([A[:, :col0], W], axis=1)
-    )(A_cur, window_next)
-
-
-def extract_r_rows(comm, C_final: jax.Array, t_lane: int, col0: int):
-    """The new R rows (global rows [col0, col0+b)) live at lane ``t_lane``'s
-    final C' block; replicate them (one b x n all-reduce — the FT broadcast)
-    and left-zero-pad back to full-width column indices."""
-    idx = comm.axis_index()
-    R_rows = comm.psum(
-        comm.where(idx == t_lane, C_final, jnp.zeros_like(C_final))
-    )
-    return comm.map_local(lambda r: jnp.pad(r, ((0, 0), (col0, 0))))(R_rows)
-
-
-def pad_bundle(bundle: RecoveryBundle, col0: int) -> RecoveryBundle:
-    """Left-zero-pad a window-width recovery bundle to full width so the
-    per-panel bundles stack (dead columns need no recovery)."""
-    return RecoveryBundle(
-        W=_pad_cols(bundle.W, col0),
-        C_self=_pad_cols(bundle.C_self, col0),
-        C_buddy=_pad_cols(bundle.C_buddy, col0),
-        Y2=bundle.Y2, T=bundle.T, self_was_top=bundle.self_was_top,
-    )
-
-
-def make_panel_factors(
-    comm, leaf_Y, leaf_T, level_Y2, level_T, row_start, active, t_lane
-) -> PanelFactors:
-    idx = comm.axis_index()
-    return PanelFactors(
-        leaf_Y=leaf_Y,
-        leaf_T=leaf_T,
-        level_Y2=level_Y2,
-        level_T=level_T,
-        row_start=row_start,
-        active=active,
-        target=jnp.broadcast_to(t_lane, jnp.shape(idx)),
-    )
-
-
-def _panel_step_windowed(comm, b: int, collect_bundles: bool, k: int, n: int):
-    """One panel of the *windowed* right-looking sweep (static ``k``).
-
-    The trailing update (leaf WY apply, per-level combines, writeback) is
-    restricted to the live window ``A[:, k*b:]`` — the panel's own columns
-    ride along because their C' rows ARE the R_kk deposit and the recovery
-    bundle must cover them; the ``k*b`` already-factored columns to the left
-    are dead (their R rows were extracted at their own panel step; what is
-    left below the frontier is annihilated garbage) and are not touched.
-    Per-column arithmetic is unchanged, so R and the live-window slice of
-    every recovery bundle are bit-identical to the full-width sweep; R rows
-    and bundles are zero-padded back to width ``n`` so the per-panel outputs
-    stack (dead columns need no recovery — their bundle slots are zero).
-
-    Fully-consumed lanes additionally skip their (identity) leaf apply via
-    ``skip_consumed`` — the frozen-row skip.
-    """
-    def body(A_cur):
-        m_loc, _n = comm.local_shape(A_cur)
-        assert _n == n
-        col0, t_lane, row_start, active = panel_geometry(comm, k, b, m_loc)
-
-        window = comm.map_local(lambda A: A[:, col0:])(A_cur)
-        panel = comm.map_local(lambda W: W[:, :b])(window)
-
-        wy = comm.map_local(householder_qr_masked)(panel, row_start)
-        leaf_Y = comm.where(active, wy.Y, jnp.zeros_like(wy.Y))
-        leaf_T = comm.where(active, wy.T, jnp.zeros_like(wy.T))
-        R_leaf = comm.where(active, wy.R, jnp.zeros_like(wy.R))
-
-        level_Y2, level_T, _Rtree = ft_tsqr_combine(
-            comm, R_leaf, t_lane, active_threshold=t_lane
-        )
-        factors = DistTSQRFactors(leaf_Y, leaf_T, level_Y2, level_T, R_leaf)
-
-        win_next, bundle, C_final = trailing_update_ft(
-            window, factors, comm, target=t_lane, row_start=row_start,
-            active=active, dead_threshold=t_lane, skip_consumed=True,
-        )
-        A_next = advance_columns(comm, A_cur, win_next, col0)
-        R_rows = extract_r_rows(comm, C_final, t_lane, col0)
-        if collect_bundles:
-            bundle = pad_bundle(bundle, col0)
-
-        panel_factors = make_panel_factors(
-            comm, leaf_Y, leaf_T, level_Y2, level_T, row_start, active, t_lane
-        )
-        out = (panel_factors, R_rows, bundle if collect_bundles else None)
-        return A_next, out
-
-    return body
-
-
-def _pad_cols(x: jax.Array, left: int) -> jax.Array:
-    """Left-pad the trailing (column) axis with zeros — realigns a windowed
-    array with full-width column indices."""
-    if left == 0:
-        return x
-    pad = [(0, 0)] * (x.ndim - 1) + [(left, 0)]
-    return jnp.pad(x, pad)
-
-
-def _panel_step(comm, b: int, collect_bundles: bool):
-    """Returns the scan body for one panel of the sweep."""
-    P = comm.axis_size()
-    idx = comm.axis_index()
-
-    def body(A_cur, k):
-        m_loc, n = comm.local_shape(A_cur)
-        col0 = k * b
-        t_lane = (k * b) // m_loc  # owner of this panel's diagonal rows
-        row_start_raw = k * b - idx * m_loc
-        active = row_start_raw < m_loc
-        row_start = jnp.clip(row_start_raw, 0, m_loc - b)
-
-        panel = comm.map_local(
-            lambda A, c: jax.lax.dynamic_slice_in_dim(A, c, b, axis=1)
-        )(A_cur, jnp.broadcast_to(col0, jnp.shape(idx)))
-
-        wy = comm.map_local(householder_qr_masked)(panel, row_start)
-        leaf_Y = comm.where(active, wy.Y, jnp.zeros_like(wy.Y))
-        leaf_T = comm.where(active, wy.T, jnp.zeros_like(wy.T))
-        R_leaf = comm.where(active, wy.R, jnp.zeros_like(wy.R))
-
-        level_Y2, level_T, _Rtree = ft_tsqr_combine(
-            comm, R_leaf, t_lane, active_threshold=t_lane
-        )
-        factors = DistTSQRFactors(leaf_Y, leaf_T, level_Y2, level_T, R_leaf)
-
-        A_next, bundle, C_final = trailing_update_ft(
-            A_cur, factors, comm, target=t_lane, row_start=row_start,
-            active=active, dead_threshold=t_lane,
-        )
-        # The new R rows (global rows [k*b, (k+1)*b)) live at lane t_lane's
-        # C' block; replicate them (one b x n all-reduce — the FT broadcast).
-        R_rows = comm.psum(
-            comm.where(idx == t_lane, C_final, jnp.zeros_like(C_final))
-        )
-
-        panel_factors = PanelFactors(
-            leaf_Y=leaf_Y,
-            leaf_T=leaf_T,
-            level_Y2=level_Y2,
-            level_T=level_T,
-            row_start=row_start,
-            active=active,
-            target=jnp.broadcast_to(t_lane, jnp.shape(idx)),
-        )
-        out = (panel_factors, R_rows, bundle if collect_bundles else None)
-        return A_next, out
-
-    return body
-
-
 def caqr_factorize(
     A_local: jax.Array,
     comm,
     panel_width: int,
     collect_bundles: bool = False,
-    use_scan: bool = True,
-    windowed: Optional[bool] = None,
 ) -> CAQRResult:
     """FT-CAQR sweep of a general matrix. Returns replicated R plus
     implicit-Q panel factors.
 
-    A_local: (m_loc, n) per lane (SimComm: (P, m_loc, n)). Any ``m x n``
-        float shape is accepted — tall, wide, ragged (``n % b != 0``) and
-        unaligned (``m_loc % b != 0``): the sweep runs at the zero-padded
-        ``sweep_geometry`` shape (exact; see module docstring) and the
-        returned R is ``(min(m, n), n)`` — square upper triangular when
-        tall, ``[R1 R2]`` when wide. Factors and bundles live at the padded
-        geometry (``caqr_apply_qt`` pads conforming inputs itself).
+    A_local: (m_loc, n) per lane (SimComm: (P, m_loc, n)), P >= 2 lanes.
+        Any ``m x n`` float shape is accepted — tall, wide, ragged
+        (``n % b != 0``) and unaligned (``m_loc % b != 0``): the sweep runs
+        at the zero-padded ``sweep_geometry`` shape (exact; see module
+        docstring) and the returned R is ``(min(m, n), n)`` — square upper
+        triangular when tall, ``[R1 R2]`` when wide. Factors and bundles
+        live at the padded geometry (``caqr_apply_qt`` pads conforming
+        inputs itself).
     panel_width: b.
-    use_scan: True = lax.scan over panels (uniform per-iteration shapes,
-        compile-time friendly; the trailing update spans all columns every
-        panel). False = statically unrolled sweep — the performance variant.
-    windowed: restrict panel k's trailing update to the live window
-        ``A[:, k*b:]`` with *static* column slices, halving the sweep's
-        trailing flops (see ``_panel_step_windowed``; outputs bit-identical
-        to the full-width sweep). Requires the unrolled path; defaults to
-        ``not use_scan``.
+
+    The sweep is the state machine of ``repro.ft.online.state`` run to
+    completion — the same panel program the fault-tolerant paths step —
+    compiled as one program; bundles nobody collects are never computed.
     """
-    b = panel_width
-    m_loc, n = comm.local_shape(A_local)
-    P = comm.axis_size()
-    geom = sweep_geometry(P, m_loc, n, b)
-    A_work = pad_to_geometry(comm, A_local, geom)
-    if windowed is None:
-        windowed = not use_scan
-    assert not (windowed and use_scan), \
-        "the windowed sweep needs static column slices (use_scan=False)"
-    n_panels, n_work = geom.n_panels, geom.n_work
+    from repro.kernels import backend
 
-    ks = jnp.arange(n_panels)
-    if use_scan:
-        body = _panel_step(comm, b, collect_bundles)
-        _, (factors, R_rows, bundles) = jax.lax.scan(body, A_work, ks)
-    else:
-        outs = []
-        A_cur = A_work
-        body = None if windowed else _panel_step(comm, b, collect_bundles)
-        for k in range(n_panels):
-            if windowed:
-                A_cur, out = _panel_step_windowed(
-                    comm, b, collect_bundles, k, n_work
-                )(A_cur)
-            else:
-                A_cur, out = body(A_cur, jnp.asarray(k))
-            outs.append(out)
-        factors = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *[o[0] for o in outs])
-        R_rows = jnp.stack([o[1] for o in outs])
-        bundles = (
-            jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *[o[2] for o in outs])
-            if collect_bundles
-            else None
-        )
+    # the kernel policy is read while tracing: a program compiled under one
+    # policy must not serve another
+    policy = (backend.dispatch_enabled(),
+              tuple(sorted(backend.engine_report().items())))
+    return _caqr_factorize(A_local, comm, panel_width, collect_bundles, policy)
 
-    # R_rows: (n_panels, b, n_work) replicated (SimComm: (n_panels, P, b, n_work)).
-    R = assemble_R(comm, R_rows, geom)
-    return CAQRResult(R=R, factors=factors, bundles=bundles)
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _caqr_factorize(A_local, comm, panel_width, collect_bundles, _policy):
+    # the state machine builds on this module's geometry: import it late
+    from repro.ft.online.state import finalize, initial_sweep_state, run_steps
+
+    state = run_steps(comm, initial_sweep_state(comm, A_local, panel_width))
+    R, factors, bundles = finalize(comm, state)
+    return CAQRResult(R=R, factors=factors,
+                      bundles=bundles if collect_bundles else None)
 
 
 def caqr_apply_qt(
     B_local: jax.Array,
     factors: PanelFactors,
     comm,
-    use_scan: bool = True,
 ) -> jax.Array:
     """Apply the implicit Q^T of a CAQR factorization to B (same row layout).
 
@@ -449,7 +268,6 @@ def caqr_apply_qt(
     them (``lstsq.caqr_lstsq`` collects deposits from exactly this layout).
     Aligned factors leave B untouched.
     """
-    n_panels = jax.tree_util.tree_leaves(factors)[0].shape[0]
     m_fac = factors.leaf_Y.shape[-2]  # the factors' (padded) per-lane rows
     m_b = comm.local_shape(B_local)[0]
     if m_b != m_fac:
@@ -469,13 +287,7 @@ def caqr_apply_qt(
         )
         return B_next, None
 
-    if use_scan:
-        B_out, _ = jax.lax.scan(body, B_local, factors)
-    else:
-        B_out = B_local
-        for k in range(n_panels):
-            pf = jax.tree_util.tree_map(lambda x: x[k], factors)
-            B_out, _ = body(B_out, pf)
+    B_out, _ = jax.lax.scan(body, B_local, factors)
     return B_out
 
 
@@ -483,34 +295,36 @@ def caqr_apply_qt(
 
 
 def caqr_factorize_batched(
-    A_batch: jax.Array, comm, panel_width: int, **kw
+    A_batch: jax.Array, comm, panel_width: int, collect_bundles: bool = False
 ) -> CAQRResult:
     """Factorize a stack of independent same-shape problems in one call.
 
     A_batch carries a leading batch axis over ``caqr_factorize``'s layout:
     (batch, P, m_loc, n) under SimComm, (batch, m_loc, n) per lane under
-    AxisComm. The whole sweep (any geometry — ragged, wide, scan or
-    windowed) is ``jax.vmap``-ed, so the batch shares one compiled program;
+    AxisComm. The whole sweep (any geometry — ragged or wide) is
+    ``jax.vmap``-ed, so the batch shares one compiled program;
     every field of the returned ``CAQRResult`` gains the leading batch axis.
     """
     return jax.vmap(
-        lambda A: caqr_factorize(A, comm, panel_width, **kw)
+        lambda A: caqr_factorize(A, comm, panel_width, collect_bundles)
     )(A_batch)
 
 
 def caqr_apply_qt_batched(
-    B_batch: jax.Array, factors: PanelFactors, comm, **kw
+    B_batch: jax.Array, factors: PanelFactors, comm
 ) -> jax.Array:
     """Batched companion of ``caqr_apply_qt``: replays a stack of
     factorizations (from ``caqr_factorize_batched``) against a conforming
     stack of right-hand sides."""
     return jax.vmap(
-        lambda B, f: caqr_apply_qt(B, f, comm, **kw)
+        lambda B, f: caqr_apply_qt(B, f, comm)
     )(B_batch, factors)
 
 
 # SPMD wrapper ---------------------------------------------------------------
 
 
-def caqr_factorize_spmd(A_local, axis_name: str, panel_width: int, **kw):
-    return caqr_factorize(A_local, AxisComm(axis_name), panel_width, **kw)
+def caqr_factorize_spmd(A_local, axis_name: str, panel_width: int,
+                        collect_bundles: bool = False):
+    return caqr_factorize(A_local, AxisComm(axis_name), panel_width,
+                          collect_bundles)

@@ -160,6 +160,14 @@ class SimComm:
     def __init__(self, P: int):
         self.P = P
 
+    # equal simulators are interchangeable: a compiled program keyed on one
+    # (a static argument of ``jax.jit``) serves every SimComm of the same P
+    def __eq__(self, other):
+        return type(other) is SimComm and other.P == self.P
+
+    def __hash__(self):
+        return hash((SimComm, self.P))
+
     def axis_size(self) -> int:
         return self.P
 

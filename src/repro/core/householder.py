@@ -155,25 +155,6 @@ def _householder_qr_masked(
     return WY(Y=Y, T=T, R=R)
 
 
-def panel_qr_apply(W: jax.Array, row_start: jax.Array, b: int):
-    """Fused leaf step: panel QR of ``W[:, :b]`` + Q^T applied to the whole
-    window + C' row extraction. Returns ``(wy, C, C_prime)``.
-
-    This is the sweep's per-lane leaf work as ONE kernel launch
-    (kernel-dispatched through the ``fused_sweep`` policy slot); the pure
-    path is the unfused composition of the primitives above.
-    """
-    if _kernel_dispatch(W):
-        from repro.kernels import ops
-
-        Y, T, R, C, Cp = ops.panel_qr_apply(W, row_start, b)
-        return WY(Y=Y, T=T, R=R), C, Cp
-    wy = _householder_qr_masked(W[:, :b], row_start)
-    C = _apply_qt(wy.Y, wy.T, W)
-    Cp = jax.lax.dynamic_slice_in_dim(C, row_start, b, axis=0)
-    return wy, C, Cp
-
-
 def householder_qr(A: jax.Array) -> WY:
     """QR of the full matrix (row_start = 0)."""
     return householder_qr_masked(A, jnp.asarray(0, jnp.int32))

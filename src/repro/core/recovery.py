@@ -252,13 +252,6 @@ def rebuild_block_row_through_panel(
     return jnp.concatenate([rows[:, :col0], window], axis=1)
 
 
-# The XOR pairing moved to the coding seam (repro.ft.coding): XORPairScheme
-# is the f=1 instance of the generalized redundancy, and xor_buddy /
-# pairing_table are its pairing algebra. Re-exported here for the existing
-# import sites (tests, elastic docs); the definitions are identical.
-from repro.ft.coding import pairing_table, xor_buddy  # noqa: E402,F401
-
-
 def tsqr_recover_r(factors: DistTSQRFactors, failed: int, source: int) -> jax.Array:
     """FT-TSQR recovery (§III-B): the restarted lane obtains R from any
     single member of its redundancy group — R is bit-identical there."""

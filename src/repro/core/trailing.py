@@ -196,7 +196,6 @@ def trailing_update_ft(
     active=None,
     dead_threshold=None,
     paper_semantics: bool = False,
-    skip_consumed: bool = False,
 ):
     """Algorithm 2: fault-tolerant trailing update.
 
@@ -221,9 +220,6 @@ def trailing_update_ft(
         bundle for *every* level (strictly more redundancy) and replicated
         tree state — this is the variant the CAQR sweep uses. Both are
         valid orthogonal reductions.
-    skip_consumed: skip the leaf apply on inactive lanes via ``lax.cond``
-        (see ``_leaf_apply``); bit-identical outputs, fewer flops under
-        SPMD. The windowed CAQR sweep sets this.
 
     Factors built on zero-padded lanes (``ft_tsqr`` with short lanes, or a
     ragged ``sweep_geometry``) carry more leaf rows than a caller's raw
@@ -253,10 +249,8 @@ def trailing_update_ft(
     if dead_threshold is None:
         dead_threshold = jnp.zeros((), jnp.int32)
 
-    C_local, C_prime = _leaf_apply(
-        comm, factors, C_local, row_start,
-        active=active, skip_consumed=skip_consumed,
-    )
+    C_local, C_prime = _leaf_apply(comm, factors, C_local, row_start,
+                                   active=active)
     C_prime = comm.where(active, C_prime, jnp.zeros_like(C_prime))
 
     Ws, Cs_self, Cs_buddy, tops = [], [], [], []

@@ -367,7 +367,7 @@ class MDSScheme(CodingScheme):
     """Vandermonde GF(2^8) MDS checksum slots tolerating any ``f``
     simultaneous deaths (module docstring has the construction and the
     exactness argument). ``f`` is the config knob traded against the
-    per-boundary encode overhead (``benchmarks/bench_coding.py``)."""
+    per-boundary encode overhead."""
 
     f: int = 2
     name = "mds"

@@ -89,9 +89,6 @@ from repro.core.comm import SimComm
 from repro.core.householder import apply_qt
 from repro.core.trailing import RecoveryBundle
 from repro.core.tsqr import _levels
-# NOTE: core.recovery re-exports from ft.coding, so by the time the line
-# above ran, repro.ft.coding is already in sys.modules — this import is a
-# cheap bind, not a cycle.
 from repro.ft.coding import CodingScheme, XORPairScheme
 from repro.ft.semantics import Semantics
 from repro.ft.failures import (
@@ -118,8 +115,7 @@ class RecoveryEvent:
     ``elapsed_s`` is wall-clock REBUILD latency, synced on both sides, under
     the online orchestrator (SimComm or shard_map heal) and the eager
     SimComm driver; under the scheduled shard_map path the whole sweep is
-    one traced program, so there it records trace time only (use
-    ``benchmarks/bench_spmd.py`` for that path's REBUILD cost).
+    one traced program, so there it records trace time only.
 
     ``xchip_bytes`` is the bytes this heal moved from one chip to another,
     counted where the heal issues each transfer (the tally of the heal
@@ -550,10 +546,11 @@ def ft_caqr_sweep(
     """Run the full windowed FT-CAQR sweep under a failure schedule
     (paper §II-III end to end).
 
-    Returns ``(R, factors, bundles, events)`` — bit-identical to
-    ``caqr_factorize(A0, comm, panel_width, collect_bundles=True,
-    use_scan=False)`` regardless of the schedule (the paper's recovery
-    guarantee), with one ``RecoveryEvent`` per REBUILD.
+    Returns ``(R, factors, bundles, events)`` — bit-identical to the
+    failure-free run regardless of the schedule (the paper's recovery
+    guarantee), with one ``RecoveryEvent`` per REBUILD. The failure-free
+    run steps the same state machine ``caqr_factorize`` compiles whole,
+    so the two agree to float32 rounding.
 
     ``semantics`` selects the FT-MPI continuation policy: REBUILD
     (default) runs this driver; SHRINK/BLANK delegate to the scheduled
@@ -578,14 +575,13 @@ def ft_caqr_sweep(
     combine, recover, and match the failure-free sweep bit for bit):
 
     >>> import numpy as np, jax.numpy as jnp
-    >>> from repro.core import SimComm, caqr_factorize
+    >>> from repro.core import SimComm
     >>> from repro.ft import FailureSchedule, ft_caqr_sweep, sweep_point
     >>> A = jnp.asarray(np.random.default_rng(0).standard_normal((2, 4, 4)),
     ...                 jnp.float32)
     >>> sched = FailureSchedule(events={sweep_point(0, "trailing", 0): [1]})
     >>> out = ft_caqr_sweep(A, SimComm(2), 4, schedule=sched)
-    >>> ref = caqr_factorize(A, SimComm(2), 4, collect_bundles=True,
-    ...                      use_scan=False)
+    >>> ref = ft_caqr_sweep(A, SimComm(2), 4)
     >>> bool(jnp.array_equal(out.R, ref.R))
     True
     >>> [(e.point, e.lane) for e in out.events]

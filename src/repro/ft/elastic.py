@@ -21,7 +21,7 @@ at the next **panel boundary** the world re-meshes:
   slot; GROW: rows re-scatter evenly over one more live lane) and the
   sweep restarts as a fresh sub-factorization on a widened
   ``sweep_geometry`` — the TSQR ladder pairing remaps implicitly to the
-  new world's XOR tree (``repro.core.recovery.pairing_table``).
+  new world's XOR tree (``repro.ft.coding.pairing_table``).
 
 Correctness: the harvested submatrix ``T`` satisfies ``T^T T =
 T_ref^T T_ref`` where ``T_ref`` is the failure-free trailing matrix

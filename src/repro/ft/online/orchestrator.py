@@ -61,13 +61,12 @@ from repro.ft.driver import (
     rebuild_state,
 )
 from repro.ft.elastic import ElasticController, ElasticSweepResult
-from repro.ft.failures import PHASE_LEAF, LaneFailure, prev_sweep_point
+from repro.ft.failures import LaneFailure, prev_sweep_point
 from repro.ft.online.detect import NaNSentinelDetector, OnlineDetector
 from repro.ft.online.state import (
     SweepState,
     finalize,
     initial_sweep_state,
-    run_panel_fused,
     run_steps,
     state_lane_axes,
 )
@@ -179,22 +178,12 @@ class SweepOrchestrator:
         NaN-sentinel probe).
     segment_points:
         Sweep points per compiled segment (>= 1). Larger segments amortize
-        host/dispatch overhead but widen the detection-latency window —
-        ``benchmarks/bench_online.py`` measures the tradeoff.
-    fused:
-        Run whole-panel fused segments (``run_panel_fused`` — the
-        ``kernels.fused_sweep`` megakernel path): O(1) dispatches per
-        panel instead of O(points * ops), with boundaries (detector polls,
-        hooks, persistence) at panel ends — the only legal fused
-        boundaries. Bitwise-identical results. ``segment_points`` is
-        ignored except to re-align a state resumed mid-panel. Mutually
-        exclusive with ``step_fn``.
-    jit_segments:
-        Compile segments with ``jax.jit`` (default). ``False`` runs them
-        eagerly — slower, handy for debugging.
+        host/dispatch overhead but widen the detection-latency window.
+        ``panel_points(geom)`` makes every boundary a panel end.
     step_fn:
         Optional external segment backend, called as ``step_fn(state) ->
-        state`` once per sweep point: the SPMD path passes the shard_map
+        state`` once per sweep point (without it, segments are compiled
+        SimComm programs): the SPMD path passes the shard_map
         runner from ``repro.launch.spmd_qr.make_spmd_sweep_step``, whose
         ``heal`` attribute gives the REBUILD program.
     fault_hooks:
@@ -253,9 +242,8 @@ class SweepOrchestrator:
         detected death discards the in-flight speculation and re-dispatches
         from the recovered state
         (``tests/test_online_recovery.py::test_async_matches_sync``).
-        REBUILD/ABORT semantics only (no elastic/straggler/fused
-        composition). ``benchmarks/bench_train.py`` gates async strictly
-        cheaper per boundary than sync.
+        REBUILD/ABORT semantics only (no elastic/straggler
+        composition).
     """
 
     def __init__(
@@ -266,8 +254,6 @@ class SweepOrchestrator:
         detector: Optional[OnlineDetector] = None,
         *,
         segment_points: int = 1,
-        fused: bool = False,
-        jit_segments: bool = True,
         step_fn: Optional[Callable[[SweepState], SweepState]] = None,
         fault_hooks: Sequence[FaultHook] = (),
         boundary_hooks: Sequence[BoundaryHook] = (),
@@ -293,13 +279,8 @@ class SweepOrchestrator:
         self.detector = detector if detector is not None else NaNSentinelDetector()
         assert segment_points >= 1
         self.segment_points = segment_points
-        assert not (fused and step_fn is not None), (
-            "fused segments replace the per-point runner; pass one or the "
-            "other")
-        self.fused = fused
-        self.jit_segments = jit_segments
         self.step_fn = step_fn
-        if step_fn is None and jit_segments:
+        if step_fn is None:
             assert isinstance(comm, SimComm), (
                 "jitted host segments need SimComm; pass step_fn= for the "
                 "shard_map backend (repro.launch.spmd_qr.make_spmd_sweep_step)"
@@ -327,7 +308,7 @@ class SweepOrchestrator:
                 "async double-buffered segments compose with REBUILD/ABORT "
                 "only; elastic transitions re-mesh the world mid-run and "
                 "would invalidate every in-flight speculation")
-            assert straggler_monitor is None and grow_at is None and not fused
+            assert straggler_monitor is None and grow_at is None
         # set by from_state: a resumed orchestrator owes the resume boundary
         # a hook/poll pass BEFORE running any segment (deaths that struck
         # while the sweep was suspended are recoverable only from the
@@ -358,28 +339,6 @@ class SweepOrchestrator:
 
     # -- segments ----------------------------------------------------------
 
-    def _stepped(self, state: SweepState, n_points: int) -> SweepState:
-        if not self.jit_segments:
-            return run_steps(self.comm, state, n_points)
-        return compiled_segment(self.comm, n_points)(state)
-
-    def _fused_segment(self, state: SweepState) -> SweepState:
-        # a state resumed mid-panel first steps to the next leaf boundary
-        # (fused segments only start there), then runs whole panels
-        while state.cursor is not None and state.cursor[1] != PHASE_LEAF:
-            state = self._stepped(state, 1)
-        if state.cursor is None:
-            return state
-        if not self.jit_segments:
-            return run_panel_fused(self.comm, state)
-        key = (type(self.comm).__name__, self.comm.axis_size(), "fused")
-        fn = _SEGMENT_CACHE.get(key)
-        if fn is None:
-            comm = self.comm
-            fn = forwarding_jit(lambda s: run_panel_fused(comm, s))
-            _SEGMENT_CACHE[key] = fn
-        return fn(state)
-
     def _segment(self, state: SweepState) -> SweepState:
         panel, phase, level = state.cursor
         with TraceAnnotation("ftqr.dispatch", panel=panel, phase=phase,
@@ -390,14 +349,7 @@ class SweepOrchestrator:
                         break
                     state = self.step_fn(state)
                 return state
-            if self.fused:
-                return self._fused_segment(state)
-            return self._stepped(state, self.segment_points)
-
-    def _finalize(self):
-        if not self.jit_segments:
-            return finalize(self.comm, self.state)
-        return compiled_finalize(self.comm)(self.state)
+            return compiled_segment(self.comm, self.segment_points)(state)
 
     # -- the host loop -----------------------------------------------------
 
@@ -467,7 +419,7 @@ class SweepOrchestrator:
                 break
         if self.elastic is not None:
             return self.elastic.finish(self.comm, self.state, self.events)
-        R, factors, bundles = self._finalize()
+        R, factors, bundles = compiled_finalize(self.comm)(self.state)
         return FTSweepResult(R=R, factors=factors, bundles=bundles,
                              events=self.events)
 
@@ -559,7 +511,7 @@ class SweepOrchestrator:
                 # dispatch is stale — re-dispatch from the real boundary
                 cur = self._segment(self.state)
                 self.segments_run += 1
-        R, factors, bundles = self._finalize()
+        R, factors, bundles = compiled_finalize(self.comm)(self.state)
         return FTSweepResult(R=R, factors=factors, bundles=bundles,
                              events=self.events)
 

@@ -1,14 +1,10 @@
-"""The reified sweep state machine: ``SweepState`` + ``sweep_step``.
+"""The FT-CAQR sweep as a state machine: ``SweepState`` + ``sweep_step``.
 
-PRs 2-4 ran the FT-CAQR sweep as ONE monolithic program whose loop state
-lived in Python locals of ``FTSweepDriver.run`` — fine for trace-time
-``FailureSchedule`` simulation, but the paper's recovery protocol is
-*online*: a process dies at an arbitrary wall-clock moment and survivors
-discover it at the next collective (Coti 2016 §II). This module extracts
-the driver's implicit loop state into an explicit, serializable pytree and
-a pure one-point transition so execution can be suspended, persisted,
-resumed, and interleaved with *runtime* failure detection
-(``repro.ft.online.detect`` / ``repro.ft.online.orchestrator``).
+This is the one panel program of the repo. ``repro.core.caqr_factorize``
+runs it to completion; the fault-tolerant paths (the scheduled driver
+``repro.ft.driver``, the online orchestrator ``repro.ft.online``, the
+shard_map segments of ``repro.launch.spmd_qr``, the QR service) step it and
+interleave failure detection and REBUILD at its boundaries.
 
 ``SweepState``
     Everything the sweep holds between two interruptible points: the
@@ -23,21 +19,21 @@ resumed, and interleaved with *runtime* failure detection
 ``sweep_step(comm, state) -> state``
     Executes exactly one sweep point — the work between the previous
     recoverable boundary and ``state.cursor`` — and advances the cursor.
-    It calls the *same* single-level primitives the monolithic sweep is
-    built from (``ft_tsqr_level``, ``trailing_combine_level``,
-    ``_leaf_apply``, the ``caqr`` geometry/deposit helpers), in the same
-    order, so iterating it to completion is **bit-identical** to the
-    monolithic windowed sweep — ``FTSweepDriver.run`` is now literally this
-    loop (there is no second floating-point program to drift).
+    Panel ``k``'s trailing update is restricted to the live window
+    ``A[:, k*b:]``: the ``k*b`` already-factored columns to the left are
+    dead (their R rows were extracted at their own panel) and are not
+    touched; per-panel R rows and bundles are zero-padded back to full
+    width so they stack.
 
 Cursor semantics (DESIGN.md §9): the boundary state after executing point
-``p`` is exactly the state the monolithic driver had at ``_checkpoint(p)``.
-Work that the monolithic driver ran *between* checkpoints is assigned to
-the segment that ENDS at the next point: panel ``k``'s writeback/deposit
-(which follows its last trailing checkpoint) runs at the start of the
-``(k+1, leaf)`` segment, and the final panel's deposit plus R assembly run
-in ``finalize``. A death injected at a boundary therefore corresponds
-one-to-one to a ``FailureSchedule`` death at the just-completed point.
+``p`` is the recoverable state "after ``p``, before its successor". Work
+that falls between two points is assigned to the segment that ENDS at the
+next point: panel ``k``'s writeback/deposit (which follows its last
+trailing combine) runs at the start of the ``(k+1, leaf)`` segment, and the
+final panel's deposit plus R assembly run in ``finalize``. A death injected
+at a boundary therefore corresponds one-to-one to a ``FailureSchedule``
+death at the just-completed point (the cursor vocabulary is
+``repro.ft.failures``).
 
 Serialization: ``sweep_state_to_host`` / ``sweep_state_from_host`` flatten
 a state to named numpy arrays plus a JSON-able meta record (geometry,
@@ -57,10 +53,7 @@ import numpy as np
 from repro.core.caqr import (
     PanelFactors,
     SweepGeometry,
-    advance_columns,
-    extract_r_rows,
-    make_panel_factors,
-    pad_bundle,
+    assemble_R,
     pad_to_geometry,
     panel_geometry,
     sweep_geometry,
@@ -76,7 +69,6 @@ from repro.core.tsqr import DistTSQRFactors, _levels, ft_tsqr_level
 from repro.ft.failures import (
     PHASE_LEAF,
     PHASE_TSQR,
-    PHASE_TRAILING,
     next_sweep_point,
     sweep_point,
 )
@@ -93,18 +85,6 @@ _ARRAY_FIELDS = (
     "code",
 )
 
-# The sweep-state wire format version written by default. v1 excluded the
-# coded parity slots as derivable state (a resumed sweep re-encodes at its
-# first boundary) — but that re-encode is a window of vulnerability: a
-# multi-death present AT the resume boundary can only be joint-decoded from
-# the parity as persisted (`SweepOrchestrator._resume_boundary_pass`), which
-# v1 threw away. v2 serializes `SweepState.code`; v1 stays loadable (the
-# regression test writes and reloads both).
-WIRE_VERSION = 2
-
-# Fields excluded from wire format v1 (the v2 writer keeps them).
-_V1_EXCLUDED_FIELDS = ("code",)
-
 
 @dataclasses.dataclass(frozen=True)
 class SweepState:
@@ -117,8 +97,7 @@ class SweepState:
     position 0 for block/leaf arrays, position 1 for level-stacked stacks).
 
     In-flight fields are ``None`` (empty tuples for the growing ladders)
-    outside the phase that defines them — exactly when the monolithic
-    driver's corresponding locals were unset.
+    outside the phase that defines them.
     """
 
     geom: SweepGeometry
@@ -183,8 +162,8 @@ jax.tree_util.register_pytree_node(SweepState, _state_flatten, _state_unflatten)
 def initial_sweep_state(comm, A0, panel_width: int) -> SweepState:
     """Entry state: padded source matrix, cursor at the first sweep point.
 
-    Accepts anything ``caqr_factorize`` accepts (tall / ragged / wide); the
-    state machine runs at the padded ``sweep_geometry`` like the driver.
+    Accepts any general shape (tall / ragged / wide); the state machine
+    runs at the padded ``sweep_geometry``.
     """
     P = comm.axis_size()
     assert _levels(P) >= 1, "need at least 2 lanes to tolerate failures"
@@ -216,25 +195,51 @@ def _begin_panel_leaf(comm, s: SweepState, k: int) -> SweepState:
 
 def _deposit_panel(comm, s: SweepState, k: int) -> SweepState:
     """Writeback + per-panel output deposit of the just-finished panel
-    ``k`` (the work the monolithic driver ran after the panel's last
-    trailing checkpoint), then clear the in-flight fields."""
+    ``k`` (the work after the panel's last trailing combine), then clear
+    the in-flight fields."""
     geom = s.geom
     col0, t_lane, row_start, active = panel_geometry(
         comm, k, geom.b, geom.m_loc_pad)
     C_out = _writeback(comm, s.C_local, s.C_prime, row_start, active)
-    A = advance_columns(comm, s.A, C_out, col0)
-    r_rows = extract_r_rows(comm, s.C_prime, t_lane, col0)
-    bundle = pad_bundle(RecoveryBundle(
+    # reattach the updated live window to the untouched dead columns
+    A = comm.map_local(
+        lambda A, W: jnp.concatenate([A[:, :col0], W], axis=1))(s.A, C_out)
+    # the new R rows (global rows [col0, col0+b)) live at lane t_lane's
+    # final C' block: replicate them (one b x n all-reduce — the FT
+    # broadcast) and left-zero-pad back to full-width column indices
+    idx = comm.axis_index()
+    r_rows = comm.psum(
+        comm.where(idx == t_lane, s.C_prime, jnp.zeros_like(s.C_prime)))
+    r_rows = comm.map_local(lambda r: jnp.pad(r, ((0, 0), (col0, 0))))(r_rows)
+    bundle = RecoveryBundle(
         W=jnp.stack(s.Ws),
         C_self=jnp.stack(s.Cs_self),
         C_buddy=jnp.stack(s.Cs_buddy),
         Y2=s.level_Y2,
         T=s.level_T,
         self_was_top=jnp.stack(s.tops),
-    ), col0)
-    pf = make_panel_factors(
-        comm, s.leaf_Y, s.leaf_T, s.level_Y2, s.level_T,
-        row_start, active, t_lane,
+    )
+
+    def pad_cols(x):
+        # left-zero-pad a window-width bundle slice to full width so the
+        # per-panel bundles stack (dead columns need no recovery)
+        if col0 == 0:
+            return x
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(col0, 0)])
+
+    bundle = bundle._replace(
+        W=pad_cols(bundle.W),
+        C_self=pad_cols(bundle.C_self),
+        C_buddy=pad_cols(bundle.C_buddy),
+    )
+    pf = PanelFactors(
+        leaf_Y=s.leaf_Y,
+        leaf_T=s.leaf_T,
+        level_Y2=s.level_Y2,
+        level_T=s.level_T,
+        row_start=row_start,
+        active=active,
+        target=jnp.broadcast_to(t_lane, jnp.shape(comm.axis_index())),
     )
     return s.replace(
         A=A,
@@ -252,11 +257,9 @@ def sweep_step(comm, state: SweepState) -> SweepState:
     ``state.cursor``) and advance the cursor.
 
     Pure and Comm-generic: under ``SimComm`` it runs eagerly or under
-    ``jax.jit`` (the orchestrator compiles it per cursor); under ``AxisComm``
-    it is the body a ``shard_map`` segment traces
-    (``repro.launch.spmd_qr.make_spmd_sweep_step``). The boundary state is
-    bit-identical to the monolithic driver's at ``_checkpoint(cursor)`` —
-    the driver *is* a loop over this function.
+    ``jax.jit`` (the orchestrator compiles it per cursor); under
+    ``AxisComm``/``MeshComm`` it is the body a ``shard_map`` segment traces
+    (``repro.launch.spmd_qr.make_spmd_sweep_step``).
     """
     point = state.cursor
     assert point is not None, "sweep already complete; call finalize"
@@ -271,17 +274,15 @@ def sweep_step(comm, state: SweepState) -> SweepState:
             state = _deposit_panel(comm, state, k - 1)
         state = _begin_panel_leaf(comm, state, k)
     elif phase == PHASE_TSQR:
-        # the monolithic driver seeds the carry with R_leaf after the leaf
-        # checkpoint — same value, assigned at the first butterfly level
+        # the carry is R_leaf at the first butterfly level
         carry = state.R_leaf if lvl == 0 else state.R_carry
         R_next, Y2, T = ft_tsqr_level(comm, carry, lvl, t_lane, t_lane)
         state = state.replace(
             R_carry=R_next, Y2s=state.Y2s + (Y2,), Ts=state.Ts + (T,))
     else:  # PHASE_TRAILING
         if lvl == 0:
-            # stack the ladder + leaf-apply the live window (the work the
-            # monolithic driver ran between the last TSQR checkpoint and
-            # the first trailing checkpoint)
+            # stack the ladder + leaf-apply the live window (the work
+            # between the last TSQR level and the first trailing combine)
             _c0, _t, row_start, active = panel_geometry(
                 comm, k, geom.b, geom.m_loc_pad)
             level_Y2 = jnp.stack(state.Y2s)
@@ -314,13 +315,11 @@ def sweep_step(comm, state: SweepState) -> SweepState:
 def finalize(comm, state: SweepState):
     """Deposit the last panel and assemble the sweep outputs.
 
-    Returns ``(R, factors, bundles)`` with the same layout as
-    ``CAQRResult(collect_bundles=True)`` / ``FTSweepResult`` — the driver
-    and the orchestrator both wrap this. Pure: the caller's state is not
-    consumed (calling twice double-runs the deposit arithmetic but on the
-    same inputs)."""
-    from repro.core.caqr import assemble_R  # local import: cycle-free either way
-
+    Returns ``(R, factors, bundles)``: R replicated ``(min(m, n), n)``,
+    factors and bundles stacked over panels — the layout of ``CAQRResult``
+    and ``FTSweepResult``. Pure: the caller's state is not consumed
+    (calling twice double-runs the deposit arithmetic but on the same
+    inputs)."""
     assert state.cursor is None, f"sweep not complete: at {state.cursor}"
     state = _deposit_panel(comm, state, state.geom.n_panels - 1)
     factors = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *state.factors)
@@ -367,76 +366,6 @@ def panel_points(geom: SweepGeometry) -> int:
     return 1 + 2 * geom.levels
 
 
-def run_panel_fused(comm, state: SweepState) -> SweepState:
-    """Execute ALL of panel ``k``'s points (leaf + L tsqr + L trailing) as
-    ONE fused dispatch — the megakernel path (``kernels.fused_sweep``).
-
-    The cursor must sit at a leaf point (panel boundaries are the only
-    legal fused boundaries — trailing level 0 needs the complete butterfly
-    ladder, so there is no intermediate fusion cut). The resulting state is
-    bitwise-identical to ``run_steps(comm, state, panel_points(geom))``:
-    the megakernel body runs the same core entry points over the same
-    ``SimComm`` program, and the panel-``(k-1)`` deposit stays outside the
-    kernel exactly as ``sweep_step`` runs it at the start of the
-    ``(k, leaf)`` segment.
-
-    Engine selection follows the ``fused_sweep`` policy slot: the Pallas
-    engines (compiled/interpret) embed ``SimComm`` and engage only under a
-    ``SimComm``; under ``AxisComm`` (or the ``xla`` engine) the same math
-    runs as one directly-traced call — still one dispatch per panel.
-    ``oracle`` mode falls back to stepping.
-    """
-    from repro.core.comm import SimComm
-    from repro.kernels import backend as _kbackend
-    from repro.kernels import fused_sweep as _fused
-
-    point = state.cursor
-    assert point is not None, "sweep already complete; call finalize"
-    k, phase, _lvl = point
-    assert phase == PHASE_LEAF, (
-        f"fused execution starts at a leaf boundary, cursor is at {point}")
-    geom = state.geom
-    L = state.levels
-
-    mode = _kbackend.kernel_mode("fused_sweep")
-    if mode == _kbackend.MODE_ORACLE or L < 1:
-        return run_steps(comm, state, panel_points(geom))
-
-    if k > 0:
-        state = _deposit_panel(comm, state, k - 1)
-    col0 = k * geom.b
-    window = comm.map_local(lambda A: A[:, col0:])(state.A)
-
-    use_pallas = isinstance(comm, SimComm) and (
-        mode == _kbackend.MODE_INTERPRET
-        or _kbackend.compiled_engine("fused_sweep") == _kbackend.ENGINE_PALLAS
-    )
-    if use_pallas:
-        res = _fused.fused_panel_pallas(
-            window, k=k, b=geom.b, m_loc_pad=geom.m_loc_pad, levels=L,
-            interpret=mode == _kbackend.MODE_INTERPRET,
-        )
-    else:
-        res = _fused.fused_panel_math(
-            comm, window, k, b=geom.b, m_loc_pad=geom.m_loc_pad, levels=L)
-
-    last = sweep_point(k, PHASE_TRAILING, L - 1)
-    return state.replace(
-        window=window,
-        leaf_Y=res["leaf_Y"], leaf_T=res["leaf_T"],
-        R_leaf=res["R_leaf"], R_carry=res["R_carry"],
-        Y2s=tuple(res["level_Y2"][l] for l in range(L)),
-        Ts=tuple(res["level_T"][l] for l in range(L)),
-        level_Y2=res["level_Y2"], level_T=res["level_T"],
-        C_local=res["C_local"], C_prime=res["C_prime"],
-        Ws=tuple(res["Ws"][l] for l in range(L)),
-        Cs_self=tuple(res["Cs_self"][l] for l in range(L)),
-        Cs_buddy=tuple(res["Cs_buddy"][l] for l in range(L)),
-        tops=tuple(res["tops"]),
-        cursor=next_sweep_point(last, geom.n_panels, L),
-    )
-
-
 # -- lane-axis bookkeeping ---------------------------------------------------
 
 _FACTORS_AXES = PanelFactors(
@@ -468,6 +397,19 @@ def state_lane_axes(state: SweepState) -> SweepState:
     # masking, NaN scans, and the SPMD specs (replicated)
     axes["code"] = like("code", -1)
     return SweepState(geom=state.geom, cursor=state.cursor, **axes)
+
+
+# The sweep-state wire format version written by default. v1 excluded the
+# coded parity slots as derivable state (a resumed sweep re-encodes at its
+# first boundary) — but that re-encode is a window of vulnerability: a
+# multi-death present AT the resume boundary can only be joint-decoded from
+# the parity as persisted (`SweepOrchestrator._resume_boundary_pass`), which
+# v1 threw away. v2 serializes `SweepState.code`; v1 stays loadable (the
+# regression test writes and reloads both).
+WIRE_VERSION = 2
+
+# Fields excluded from wire format v1 (the v2 writer keeps them).
+_V1_EXCLUDED_FIELDS = ("code",)
 
 
 # -- host serialization (the SweepState wire format, DESIGN.md §9) -----------

@@ -3,7 +3,6 @@
 panel_qr    - Householder panel factorization (geqrt) in VMEM
 stacked_qr  - TSQR tree combine (tpqrt) + fused trailing combine
 wy_apply    - fused compact-WY application C - Y (T^T (Y^T C))
-fused_sweep - whole-panel sweep megakernel + fused leaf (panel QR + apply)
 
 ops.py is the dispatch seam ``repro.core`` routes through: wrappers that
 resolve the per-op execution policy (compiled pallas / compiled xla /

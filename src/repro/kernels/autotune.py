@@ -117,11 +117,10 @@ def candidates(op: str, variant: str) -> List[Dict[str, int]]:
         else:
             pads = (backend.SUBLANE, 32, backend.LANE)
         return [{"lane_pad": p} for p in pads]
-    if op in ("wy_apply", "stacked_apply"):
-        if variant == backend.ENGINE_XLA:
-            return [{}]  # untiled: column tiling is a pallas-grid concept
-        return [{"block_n": n} for n in (64, 128, 256, 512)]
-    return [{}]  # fused_sweep: no tunables yet (whole window resident)
+    # wy_apply, stacked_apply
+    if variant == backend.ENGINE_XLA:
+        return [{}]  # untiled: column tiling is a pallas-grid concept
+    return [{"block_n": n} for n in (64, 128, 256, 512)]
 
 
 def _median_us(fn, reps: int) -> float:

@@ -14,13 +14,8 @@ Three independent decisions live here:
 * ``compiled_engine(op)`` — which engine ``compiled`` runs, a written,
   static policy (no probe, no fallback):
 
-      on TPU     ``pallas`` (native Mosaic ``pallas_call``) for every op in
-                 ``PALLAS_ON_TPU``: the four tile kernels. ``fused_sweep``
-                 runs ``xla`` — the whole-panel megakernel keeps its whole
-                 (m, w) window resident in VMEM, 128 MiB per lane at the
-                 production leaf (8192 x 4096 f32), which no TPU core holds;
-                 compiled XLA over the same core math keeps one dispatch
-                 per panel and the tile kernels inside it stay Pallas.
+      on TPU     ``pallas`` (native Mosaic ``pallas_call``) for every op:
+                 the four tile kernels.
       elsewhere  ``xla`` for every op (the tile program as plain compiled
                  XLA; the Pallas TPU kernels do not lower on CPU).
 
@@ -73,9 +68,8 @@ KERNEL_MODES = (MODE_COMPILED, MODE_INTERPRET, MODE_ORACLE)
 ENGINE_PALLAS = "pallas"
 ENGINE_XLA = "xla"
 
-# Every op the ops layer dispatches (fused_sweep is the multi-point
-# megakernel in repro.kernels.fused_sweep).
-OPS = ("panel_qr", "stacked_qr", "wy_apply", "stacked_apply", "fused_sweep")
+# Every op the ops layer dispatches.
+OPS = ("panel_qr", "stacked_qr", "wy_apply", "stacked_apply")
 
 _MODE_OVERRIDE: Dict[str, str] = {}  # op (or "*") -> mode
 
@@ -135,11 +129,6 @@ def kernel_mode(op: str) -> str:
 
 # -- the static engine policy -------------------------------------------------
 
-# Ops whose compiled engine on TPU is the native Pallas kernel (see the module
-# docstring for why ``fused_sweep`` is not among them).
-PALLAS_ON_TPU = ("panel_qr", "stacked_qr", "wy_apply", "stacked_apply")
-
-
 def platform() -> str:
     """The default backend's platform (``tpu``, ``cpu``, ...)."""
     return jax.default_backend()
@@ -148,7 +137,7 @@ def platform() -> str:
 def compiled_engine(op: str) -> str:
     """Which engine ``compiled`` mode runs for ``op`` on this backend."""
     assert op in OPS, op
-    if platform() == "tpu" and op in PALLAS_ON_TPU:
+    if platform() == "tpu":
         return ENGINE_PALLAS
     return ENGINE_XLA
 

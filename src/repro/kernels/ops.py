@@ -5,12 +5,11 @@ for when). Each call resolves the per-op policy (``backend.kernel_mode`` —
 compiled / interpret / oracle) at trace time and routes accordingly:
 
 * **compiled / pallas** — native non-interpret ``pallas_call`` (Mosaic),
-  the engine on TPU for the ops in ``backend.PALLAS_ON_TPU``.
+  the engine on TPU.
 * **compiled / xla** — the op's tile program as plain compiled XLA
-  (``*_xla`` in the kernel modules): the engine off TPU, and for
-  ``fused_sweep`` everywhere (``backend.compiled_engine`` is the written
-  policy). No alignment contract: runs at natural shapes, no padding
-  copies.
+  (``*_xla`` in the kernel modules): the engine off TPU
+  (``backend.compiled_engine`` is the written policy). No alignment
+  contract: runs at natural shapes, no padding copies.
 * **interpret** — the Pallas interpreter; the validation vehicle, never
   chosen automatically.
 * **oracle** — the pure-jnp reference in ``ref.py``; also the automatic
@@ -209,23 +208,3 @@ def stacked_apply(Y2, T, C_top, C_bot, block_n: Optional[int] = None):
         block_n=bn, interpret=interp,
     )
     return ot[:b], ob[:b], W[:b]
-
-
-def panel_qr_apply(W: jax.Array, row_start=0, b: Optional[int] = None):
-    """Fused leaf step: panel QR of ``W[:, :b]`` + WY-apply of the whole
-    window + C' row extraction, one launch. Returns (Y, T, R, C, C_prime).
-
-    Governed by the ``fused_sweep`` policy slot; the oracle route composes
-    the unfused oracles.
-    """
-    from repro.kernels import fused_sweep as _fused
-
-    if b is None:
-        b = W.shape[1]
-    route = _route("fused_sweep", W)
-    if route == _R_ORACLE:
-        return _fused.panel_qr_apply_ref(W, row_start, b)
-    rs = jnp.asarray(row_start, jnp.int32)
-    if route == _R_XLA:
-        return _fused.panel_qr_apply_xla(W, rs, b)
-    return _fused.panel_qr_apply(W, rs, b, interpret=route == _R_INTERPRET)

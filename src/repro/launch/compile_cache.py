@@ -2,7 +2,7 @@
 
 Every sweep point is its own compiled program (the cursor is static aux
 data of ``SweepState``), so a cold production sweep compiles a few hundred
-small programs. The entry points — ``chip_smoke.py``, ``benchmarks/run.py``,
+small programs. The entry points — ``chip_smoke.py``, ``bench/run_cell.py``,
 ``python -m repro.launch.serve_qr``, ``python -m repro.launch.train`` — call
 :func:`enable_compile_cache` at start-up; the library never does.
 """

@@ -435,13 +435,6 @@ def run_caqr_cell(mesh_kind: str, out_dir: str, m_rows: int = 65536,
     ca = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     coll = _collective_bytes(hlo, lanes)
-    # the panel sweep is a lax.scan: XLA counts the while body once ->
-    # multiply by the trip count (n_panels)
-    trips = n_cols // panel
-    ca = {k: (v * trips if isinstance(v, float) else v) for k, v in ca.items()}
-    for k in list(coll):
-        if isinstance(coll[k], float):
-            coll[k] *= trips
     # CAQR model flops: 2 m n^2 - (2/3) n^3
     mf = 2 * m_rows * n_cols**2 - (2 / 3) * n_cols**3
     rec = {

@@ -21,8 +21,7 @@ oracle, gated by ``tests/test_spmd_ft_driver.py`` on aligned, ragged, and
 wide geometries.
 
 Scheduling caveats inherited from tracing the whole sweep into one program:
-``RecoveryEvent.elapsed_s`` records trace time, not device time (use
-``benchmarks/bench_spmd.py`` for measured SPMD REBUILD cost), and an
+``RecoveryEvent.elapsed_s`` records trace time, not device time, and an
 unrecoverable schedule raises ``UnrecoverableFailure`` at trace time,
 before any device computes.
 

@@ -30,9 +30,8 @@ decode engine's prefill/insert/generate loop transposed onto panel sweeps:
   carries its own ``NaNSentinelDetector``; the boundary poll discovers the
   death and the same ``recover_lanes`` REBUILD the orchestrator uses heals
   each tenant from its XOR-buddy bundles — no request is dropped, and
-  every retired R stays bitwise-identical to a failure-free solo
-  ``caqr_factorize`` of the same bucket-padded matrix
-  (``tests/test_serve.py``).
+  every retired R stays bitwise-identical to the R of the same request
+  served alone with no failure (``tests/test_serve.py``).
 
 Least squares rides the factorization: a request with a right-hand side is
 admitted as the augmented matrix ``[A | b]`` (the rhs columns sit beyond
@@ -43,8 +42,8 @@ including the wide-problem *basic* solution.
 
 ``drain_batched`` is the express static-batch path for offline bulk work:
 group the queue by bucket and run each group through
-``caqr_factorize_batched`` (one vmapped program per bucket) — identical
-results, no mid-flight admission. The serve bench compares both modes.
+``caqr_factorize_batched`` (one vmapped program per bucket) — the same
+results to float32 rounding, no mid-flight admission.
 """
 from __future__ import annotations
 
@@ -341,8 +340,8 @@ class QRService:
         each group through ``caqr_factorize_batched`` (one vmapped sweep
         per bucket — the batched bucket dispatch), bypassing the slot
         machinery. No mid-flight admission or failure handling; results
-        match the continuous path (bitwise at small tiles — see
-        ``tests/test_serve.py``)."""
+        match the continuous path to float32 rounding
+        (``tests/test_serve.py``)."""
         by_bucket: Dict[Tuple[int, int], List[QRRequest]] = {}
         queue, self.queue = self.queue, []
         for req in queue:
@@ -359,8 +358,7 @@ class QRService:
                     self.P, m_loc, n_b)
                 for r in reqs])
             res = caqr_factorize_batched(
-                stack, self.comm, self.b, use_scan=False,
-                collect_bundles=True)
+                stack, self.comm, self.b, collect_bundles=True)
             geom = sweep_geometry(self.P, m_loc, n_b, self.b)
             for i, req in enumerate(reqs):
                 m_req, n_req = req.shape

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import SimComm, sweep_geometry
+from repro.ft.online.state import initial_sweep_state, sweep_step
 from repro.ft import (
     FailureSchedule,
     MDSScheme,
@@ -56,7 +57,6 @@ from repro.ft.coding import (
 )
 from repro.ft.driver import obliterate_state
 from repro.ft.online.detect import ScriptedKiller
-from repro.ft.online.state import initial_sweep_state, sweep_step
 
 try:
     from hypothesis import given, settings

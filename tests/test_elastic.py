@@ -15,10 +15,10 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.core import SimComm, caqr_factorize
+from repro.core import SimComm
 from repro.core.caqr import sweep_geometry
-from repro.core.recovery import pairing_table, xor_buddy
 from repro.core.tsqr import _levels, _xor_perm
+from repro.ft.coding import pairing_table, xor_buddy
 from repro.ft import (
     FailureSchedule,
     Semantics,
@@ -39,6 +39,7 @@ from repro.ft.elastic import (
 )
 from repro.ft.failures import UnrecoverableFailure
 from repro.ft.online.detect import ScriptedKiller
+from sweep_reference import eager_sweep
 
 
 def signfix(R):
@@ -62,8 +63,7 @@ def _matrix(P, m_loc, n, seed=3):
 @pytest.fixture(scope="module")
 def ragged_reference():
     A = _matrix(RP, RM_LOC, RN)
-    ref = caqr_factorize(A, SimComm(RP), RB, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(RP), RB)
     return A, np.asarray(ref.R[0])
 
 
@@ -213,8 +213,7 @@ def test_buddy_pair_death_still_unrecoverable(ragged_reference):
 def test_shrink_aligned_and_wide_shapes():
     for P, m_loc, n, b in [(4, 8, 32, 4), (2, 8, 8, 4), (4, 4, 24, 4)]:
         A = _matrix(P, m_loc, n, seed=11)
-        ref = caqr_factorize(A, SimComm(P), b, collect_bundles=True,
-                             use_scan=False)
+        ref = eager_sweep(A, SimComm(P), b)
         geom = sweep_geometry(P, m_loc, n, b)
         pts = list(iter_sweep_points(geom.n_panels, geom.levels))
         sched = FailureSchedule(events={pts[len(pts) // 2]: [1]})
@@ -240,8 +239,7 @@ def test_speculative_recompute_bitwise():
     full event ledger stay exactly the failure-free result."""
     P, m_loc, n, b = 4, 8, 32, 4
     A = _matrix(P, m_loc, n, seed=0)
-    ref = caqr_factorize(A, SimComm(P), b, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(P), b)
     mon = StragglerMonitor(P, StragglerConfig(
         threshold=1.4, patience=2, policy=StragglerPolicy.SPECULATE))
     orch = SweepOrchestrator(A, SimComm(P), b, straggler_monitor=mon,
@@ -258,8 +256,7 @@ def test_speculative_recompute_bitwise():
 def test_evict_escalates_to_shrink():
     P, m_loc, n, b = 4, 8, 32, 4
     A = _matrix(P, m_loc, n, seed=0)
-    ref = caqr_factorize(A, SimComm(P), b, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(P), b)
     mon = StragglerMonitor(P, StragglerConfig(
         threshold=1.4, patience=2, policy=StragglerPolicy.EVICT))
 
@@ -281,8 +278,7 @@ def test_evict_escalates_to_shrink():
 def test_speculate_escalate_after():
     P, m_loc, n, b = 4, 8, 32, 4
     A = _matrix(P, m_loc, n, seed=0)
-    ref = caqr_factorize(A, SimComm(P), b, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(P), b)
     mon = StragglerMonitor(P, StragglerConfig(
         threshold=1.4, patience=2, policy=StragglerPolicy.SPECULATE,
         escalate_after=2))
@@ -344,7 +340,10 @@ def test_harvest_covers_all_padded_rows(ragged_reference):
     trailing-matrix content when m_loc < m_loc_pad) — coverage check of
     the frontier arithmetic."""
     from repro.ft.online.state import (
-        deposit_boundary, initial_sweep_state, run_steps)
+        deposit_boundary,
+        initial_sweep_state,
+        run_steps,
+    )
 
     A, _ = ragged_reference
     comm = SimComm(RP)

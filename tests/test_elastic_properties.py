@@ -18,7 +18,7 @@ import pytest
 pytest.importorskip("hypothesis", reason="hypothesis not in this image")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import SimComm, caqr_factorize
+from repro.core import SimComm
 from repro.core.caqr import sweep_geometry
 from repro.ft import (
     FailureSchedule,
@@ -30,6 +30,7 @@ from repro.ft import (
 )
 from repro.ft.online.detect import ScriptedKiller
 from repro.kernels.ref import tolerances
+from sweep_reference import eager_sweep
 
 _SETTINGS = dict(max_examples=15, deadline=None)
 
@@ -65,8 +66,7 @@ def _case(geom_idx, point_frac, lane_frac, seed):
     A = jnp.asarray(
         np.random.default_rng(seed).standard_normal((P, m_loc, n)),
         jnp.float32)
-    ref = caqr_factorize(A, SimComm(P), b, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(P), b)
     return P, b, A, np.asarray(ref.R[0]), point, lane, points
 
 

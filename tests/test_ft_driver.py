@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import SimComm, caqr_factorize, sweep_geometry
+from repro.core import SimComm, sweep_geometry
 from repro.ft import (
     FailureSchedule,
     UnrecoverableFailure,
@@ -19,6 +19,7 @@ from repro.ft import (
     iter_sweep_points,
     sweep_point,
 )
+from sweep_reference import eager_sweep
 
 # square case: the sweep crosses row-ownership boundaries, so the kill
 # matrix covers target-lane rotation and consumed (inactive) lanes too
@@ -46,7 +47,7 @@ def _assert_bit_identical(got, ref):
 @pytest.fixture(scope="module")
 def reference():
     A = _matrix()
-    ref = caqr_factorize(A, SimComm(P), B, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, SimComm(P), B)
     return A, ref
 
 
@@ -158,7 +159,7 @@ def test_kill_matrix_p8_spot(point, lane):
     P8, m8, n8, b8 = 8, 8, 32, 4
     A = _matrix(P8, m8, n8, seed=1)
     comm = SimComm(P8)
-    ref = caqr_factorize(A, comm, b8, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, comm, b8)
     got = ft_caqr_sweep(A, comm, b8, schedule=FailureSchedule(events={point: [lane]}))
     _assert_bit_identical(got, ref)
 
@@ -177,8 +178,7 @@ assert (RGEOM.m_loc_pad, RGEOM.n_work, RGEOM.n_panels) == (8, 12, 3)
 @pytest.fixture(scope="module")
 def ragged_reference():
     A = _matrix(RP, RM_LOC, RN, seed=3)
-    ref = caqr_factorize(A, SimComm(RP), RB, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(RP), RB)
     return A, ref
 
 
@@ -216,7 +216,7 @@ def test_kill_matrix_wide_spot():
     Pw, mw, nw, bw = 4, 4, 24, 4
     A = _matrix(Pw, mw, nw, seed=4)
     comm = SimComm(Pw)
-    ref = caqr_factorize(A, comm, bw, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, comm, bw)
     sched = FailureSchedule(events={sweep_point(2, "trailing", 1): [2]})
     got = ft_caqr_sweep(A, comm, bw, schedule=sched)
     _assert_bit_identical(got, ref)
@@ -243,7 +243,7 @@ def test_kill_matrix_p8_exhaustive(lane):
     P8, m8, n8, b8 = 8, 16, 32, 4
     A = _matrix(P8, m8, n8, seed=2)
     comm = SimComm(P8)
-    ref = caqr_factorize(A, comm, b8, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, comm, b8)
     for k in range(n8 // b8):
         for pt in (
             [sweep_point(k, "leaf")]

@@ -21,9 +21,9 @@ import jax.numpy as jnp
 from repro.configs import get_smoke
 from repro.core.caqr import block_row_layout
 from repro.core.comm import SimComm
+from repro.ft.failures import prev_sweep_point
 from repro.data.pipeline import DataConfig
 from repro.ft.coding import MDSScheme, UnrecoverableFailure
-from repro.ft.failures import prev_sweep_point
 from repro.ft.online.detect import NaNSentinelDetector, ScriptedKiller
 from repro.ft.online.orchestrator import SweepOrchestrator
 from repro.ft.semantics import Semantics

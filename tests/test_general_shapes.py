@@ -5,7 +5,8 @@ this file is where "general" is enforced. Every shape class the padded
 ``sweep_geometry`` unlocks (ragged last panel, unaligned lane heights, wide
 matrices, degenerate tiny problems) is run differentially against
 ``numpy.linalg.qr`` (sign-fixed R), the Gram identity, and the implicit-Q
-replay's orthogonality, on both sweep variants plus the batched front-end.
+replay's orthogonality, with and without bundles, plus the batched
+front-end.
 
 Two tiers live here:
 
@@ -32,6 +33,7 @@ from repro.core import (
     sweep_geometry,
 )
 from repro.core.lstsq import caqr_lstsq
+from sweep_reference import eager_sweep
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -47,14 +49,16 @@ def _signfix(R):
     return R * s[:, None]
 
 
-def _check_general_shape(P, m_loc, n, b, scale=1.0, seed=0, **kw):
+def _check_general_shape(P, m_loc, n, b, scale=1.0, seed=0,
+                         collect_bundles=False):
     """The differential oracle: one general-shape factorization, checked
     against numpy's QR (sign-fixed), the Gram identity, and the replayed
     implicit Q's orthogonality."""
     rng = np.random.default_rng(seed)
     A = (rng.standard_normal((P, m_loc, n)) * scale).astype(np.float32)
     comm = SimComm(P)
-    res = caqr_factorize(jnp.asarray(A), comm, b, **kw)
+    res = caqr_factorize(jnp.asarray(A), comm, b,
+                         collect_bundles=collect_bundles)
 
     Af = A.reshape(-1, n)
     K = min(P * m_loc, n)
@@ -99,7 +103,9 @@ CASES = {
 
 @pytest.mark.parametrize("shape", CASES.values(), ids=CASES.keys())
 def test_general_shapes_scan_sweep(shape):
-    _check_general_shape(*shape)
+    """Without bundles: the compiled sweep computes none."""
+    res = _check_general_shape(*shape)
+    assert res.bundles is None
 
 
 @pytest.mark.parametrize(
@@ -108,8 +114,12 @@ def test_general_shapes_scan_sweep(shape):
     ids=["ragged-both", "wide-ragged", "short-lanes"],
 )
 def test_general_shapes_windowed_sweep(shape):
-    """The unrolled windowed perf path handles the same general shapes."""
-    _check_general_shape(*shape, use_scan=False)
+    """With bundles collected, the same general shapes: the bundles live
+    at the padded geometry, one per panel."""
+    res = _check_general_shape(*shape, collect_bundles=True)
+    geom = sweep_geometry(shape[0], shape[1], shape[2], shape[3])
+    assert res.bundles.W.shape[0] == geom.n_panels
+    assert res.bundles.W.shape[-1] == geom.n_work
 
 
 def test_scales_do_not_break_raggedness():
@@ -121,7 +131,9 @@ def test_ragged_equals_explicitly_padded_aligned_bitwise():
     """The contract behind the whole refactor, stated bitwise: factorizing a
     ragged matrix IS factorizing its zero-padded aligned embedding — same
     ops, same floats. (This is also what pins the aligned path to the seed:
-    aligned inputs take the identical code with zero padding elided.)"""
+    aligned inputs take the identical code with zero padding elided.) Both
+    run the sweep stepped eagerly, so the same ops run on the same floats
+    whatever XLA would fuse around a pad inside one compiled program."""
     P, m_loc, n, b = 4, 6, 10, 4
     rng = np.random.default_rng(11)
     A = jnp.asarray(rng.standard_normal((P, m_loc, n)), jnp.float32)
@@ -130,9 +142,8 @@ def test_ragged_equals_explicitly_padded_aligned_bitwise():
     A_pad = pad_to_geometry(comm, A, geom)
     assert A_pad.shape == (P, geom.m_loc_pad, geom.n_work)
 
-    ragged = caqr_factorize(A, comm, b, collect_bundles=True, use_scan=False)
-    aligned = caqr_factorize(A_pad, comm, b, collect_bundles=True,
-                             use_scan=False)
+    ragged = eager_sweep(A, comm, b)
+    aligned = eager_sweep(A_pad, comm, b)
     # R: the ragged result is the [:k, :n] slice of the aligned assembly
     assert np.array_equal(
         np.asarray(ragged.R), np.asarray(aligned.R)[:, :geom.k, :n]
@@ -145,15 +156,18 @@ def test_ragged_equals_explicitly_padded_aligned_bitwise():
         assert np.array_equal(np.asarray(g), np.asarray(r))
 
 
-def test_windowed_matches_scan_on_ragged(rng):
+def test_ragged_against_float64(rng):
+    """The ragged geometry (unaligned lanes and a ragged last panel) against
+    the float64 Gram matrix and float64 LAPACK R."""
     P, m_loc, n, b = 4, 6, 10, 4
     comm = SimComm(P)
     A = jnp.asarray(rng.standard_normal((P, m_loc, n)), jnp.float32)
-    scan = caqr_factorize(A, comm, b, use_scan=True)
-    win = caqr_factorize(A, comm, b, use_scan=False)
-    np.testing.assert_allclose(
-        np.asarray(scan.R), np.asarray(win.R), rtol=1e-6, atol=1e-6
-    )
+    R = np.asarray(caqr_factorize(A, comm, b).R[0], np.float64)
+    Af = np.asarray(A, np.float64).reshape(-1, n)
+    G = Af.T @ Af
+    np.testing.assert_allclose(R.T @ R, G, rtol=0, atol=1e-5 * np.abs(G).max())
+    np.testing.assert_allclose(_signfix(R), _signfix(np.linalg.qr(Af, "r")),
+                               rtol=0, atol=1e-5 * np.abs(R).max())
 
 
 def test_batched_vmap_front_end(rng):

@@ -133,12 +133,12 @@ def test_forced_kernel_caqr_sweep_matches_pure(rng):
     A = jnp.asarray(rng.standard_normal((P, m_loc, n)), jnp.float32)
     backend.use_kernels(True)
     try:
-        R_k = np.asarray(caqr_factorize(A, comm, b, use_scan=False).R[0])
+        R_k = np.asarray(caqr_factorize(A, comm, b).R[0])
     finally:
         backend.use_kernels(None)
     backend.use_kernels(False)
     try:
-        R_p = np.asarray(caqr_factorize(A, comm, b, use_scan=False).R[0])
+        R_p = np.asarray(caqr_factorize(A, comm, b).R[0])
     finally:
         backend.use_kernels(None)
     np.testing.assert_allclose(R_k, R_p, rtol=3e-4, atol=3e-4)
@@ -253,15 +253,12 @@ def test_static_engine_policy_off_tpu(clean_policy):
 
 
 def test_tpu_lowering_failure_raises(clean_policy, rng):
-    """On a TPU-like backend the tile ops route to native Pallas and
-    ``fused_sweep`` to xla; a Pallas kernel that cannot lower (here: the
-    CPU backend underneath) raises its lowering error — no fallback to
-    another engine."""
+    """On a TPU-like backend every op routes to native Pallas; a Pallas
+    kernel that cannot lower (here: the CPU backend underneath) raises its
+    lowering error — no fallback to another engine."""
     clean_policy.setattr(backend, "platform", lambda: "tpu")
     for op in backend.OPS:
-        want = (backend.ENGINE_PALLAS if op in backend.PALLAS_ON_TPU
-                else backend.ENGINE_XLA)
-        assert backend.compiled_engine(op) == want
+        assert backend.compiled_engine(op) == backend.ENGINE_PALLAS
     A = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
     with pytest.raises(Exception, match="(?i)interpret"):
         jax.block_until_ready(ops.panel_qr(A, 0))

@@ -178,12 +178,6 @@ def test_parity_matrix_ragged(rng, route, dtype, m, b, n):
     _allclose(ops.stacked_apply(T, T, Ct, Cb),
               ref.stacked_apply(T, T, Ct, Cb), dtype=dtype)
 
-    from repro.kernels import fused_sweep as _fused
-
-    W = jnp.asarray(rng.standard_normal((m, b + 7)), dtype)
-    _allclose(ops.panel_qr_apply(W, 0, b),
-              _fused.panel_qr_apply_ref(W, 0, b), dtype=dtype)
-
 
 @pytest.mark.parametrize("op", backend.OPS)
 def test_native_pallas_parity(rng, op):
@@ -205,19 +199,13 @@ def test_native_pallas_parity(rng, op):
                                      jnp.float32)) * 0.1
             C = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
             _allclose(ops.wy_apply(Y, T, C), ref.wy_apply(Y, T, C))
-        elif op == "stacked_apply":
+        else:  # stacked_apply
             T = jnp.triu(jnp.asarray(rng.standard_normal((8, 8)),
                                      jnp.float32)) * 0.1
             Ct = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
             Cb = jnp.asarray(rng.standard_normal((8, 16)), jnp.float32)
             _allclose(ops.stacked_apply(T, T, Ct, Cb),
                       ref.stacked_apply(T, T, Ct, Cb))
-        else:  # fused_sweep
-            from repro.kernels import fused_sweep as _fused
-
-            W = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
-            _allclose(ops.panel_qr_apply(W, 0, 8),
-                      _fused.panel_qr_apply_ref(W, 0, 8))
     finally:
         backend.force_mode(None, op)
 

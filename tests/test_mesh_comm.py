@@ -25,8 +25,8 @@ import json
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core import MeshComm, SimComm
-from repro.dist import compat
 from repro.ft.failures import sweep_point
+from repro.dist import compat
 from repro.ft.online.detect import ScriptedKiller
 from repro.ft.online.orchestrator import ft_caqr_sweep_online
 from repro.launch.spmd_qr import ft_caqr_sweep_online_spmd, make_lane_mesh

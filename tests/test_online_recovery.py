@@ -1,14 +1,17 @@
 """Online recovery: the reified sweep state machine under runtime detection.
 
 The scheduled (trace-time ``FailureSchedule``) driver is the differential
-oracle throughout: iterating ``sweep_step`` to completion must be
-bit-identical to the monolithic sweep, and a *runtime-detected* kill —
+oracle throughout: iterating jitted ``sweep_step`` to completion must be
+bit-identical to the same transition stepped eagerly, and a
+*runtime-detected* kill —
 poison injected at a segment boundary, discovered by the NaN-sentinel
 probe, rebuilt by the orchestrator — must produce output bit-identical to
 the same kill expressed as a trace-time schedule (and hence to the
 failure-free sweep). Also covered: two failures in different panels, a
 detector false-negative surfacing one segment late, suspend/persist/resume
-through ``repro.ckpt`` (numpy round-trip), and the diskless snapshot store.
+through ``repro.ckpt`` (numpy round-trip), the diskless snapshot store, and
+panel-sized segments (checked against the float64 reference and the REBUILD
+log, not bit for bit: their compiled programs differ from the eager sweep's).
 """
 import functools
 import os
@@ -18,7 +21,15 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import SimComm, caqr_factorize, sweep_geometry
+from repro.core import SimComm, sweep_geometry
+from repro.ft.failures import next_sweep_point, prev_sweep_point
+from repro.ft.online.state import (
+    finalize,
+    initial_sweep_state,
+    panel_points,
+    run_steps,
+    sweep_step,
+)
 from repro.ckpt import load_sweep_state, save_sweep_state
 from repro.ckpt.diskless import SweepStateStore
 from repro.ft import (
@@ -30,7 +41,7 @@ from repro.ft import (
     iter_sweep_points,
     sweep_point,
 )
-from repro.ft.failures import LaneFailure, next_sweep_point, prev_sweep_point
+from repro.ft.failures import LaneFailure
 from repro.ft.online.detect import (
     DelayedDetector,
     FailStopDetector,
@@ -38,15 +49,9 @@ from repro.ft.online.detect import (
     ScriptedKiller,
     WallClockKiller,
 )
-from repro.ft.online.state import (
-    finalize,
-    initial_sweep_state,
-    run_steps,
-    sweep_state_from_host,
-    sweep_state_to_host,
-    sweep_step,
-)
+from repro.ft.online.state import sweep_state_from_host, sweep_state_to_host
 from repro.ft.semantics import Semantics
+from sweep_reference import eager_sweep
 
 # the PR-3 ragged geometry: unaligned lane heights AND a ragged last panel
 RP, RM_LOC, RN, RB = 4, 6, 10, 4
@@ -78,8 +83,7 @@ def _assert_same_events(got, sched):
 @pytest.fixture(scope="module")
 def ragged_reference():
     A = _matrix()
-    ref = caqr_factorize(A, SimComm(RP), RB, collect_bundles=True,
-                         use_scan=False)
+    ref = eager_sweep(A, SimComm(RP), RB)
     return A, ref
 
 
@@ -90,12 +94,12 @@ def ragged_reference():
     ("aligned", 8, 16, 4), ("ragged", RM_LOC, RN, RB), ("wide", 4, 24, 4),
 ], ids=lambda s: s[0])
 def test_stepped_iteration_matches_monolithic(shape):
-    """Iterating jitted sweep_step to completion + finalize == the
-    monolithic windowed sweep, bit for bit, on every geometry class."""
+    """Iterating jitted sweep_step to completion + finalize == the same
+    transition stepped eagerly, bit for bit, on every geometry class."""
     _, m_loc, n, b = shape
     comm = SimComm(4)
     A = _matrix(4, m_loc, n, seed=5)
-    ref = caqr_factorize(A, comm, b, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, comm, b)
     step = jax.jit(functools.partial(sweep_step, comm))
     s = initial_sweep_state(comm, A, b)
     points = []
@@ -177,7 +181,7 @@ def test_online_kill_other_geometries(geom):
     _, m_loc, n, b, point, lane = geom
     comm = SimComm(4)
     A = _matrix(4, m_loc, n, seed=7)
-    ref = caqr_factorize(A, comm, b, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, comm, b)
     got = ft_caqr_sweep_online(
         A, comm, b, fault_hooks=[ScriptedKiller({point: [lane]})])
     _assert_bit_identical(got, ref)
@@ -438,6 +442,78 @@ def test_segmented_execution_and_boundary_kill(ragged_reference):
     _assert_same_events(got, sched)
 
 
+# -- panel-sized segments: every boundary a panel end -----------------------
+
+# (tag, P, m_loc, n, b): aligned, ragged and wide geometry classes at b = 4
+GEOMS = [
+    ("aligned", 4, 8, 16, 4),
+    ("ragged", RP, RM_LOC, RN, RB),
+    ("wide", 4, 4, 40, 4),
+]
+
+
+def _assert_r_matches_float64(R, A):
+    """R is triangular, the same on every lane, and ``R^T R`` is the
+    float64 ``A^T A``."""
+    R = np.asarray(R)
+    assert np.all(R == R[0]) and not np.any(np.tril(R[0], -1))
+    Af = np.asarray(A, np.float64).reshape(-1, A.shape[-1])
+    G = Af.T @ Af
+    Rd = R[0].astype(np.float64)
+    np.testing.assert_allclose(Rd.T @ Rd, G, rtol=0,
+                               atol=1e-5 * np.abs(G).max())
+
+
+@pytest.mark.parametrize("geom", GEOMS, ids=lambda g: g[0])
+def test_panel_segments_failure_free(geom):
+    """One compiled segment per panel: n_panels segments, no events."""
+    _, P, m_loc, n, b = geom
+    A = _matrix(P, m_loc, n)
+    pts = panel_points(sweep_geometry(P, m_loc, n, b))
+    orch = SweepOrchestrator(A, SimComm(P), b, segment_points=pts)
+    got = orch.run()
+    _assert_r_matches_float64(got.R, A)
+    assert got.events == []
+    assert orch.segments_run == orch.state.geom.n_panels
+
+
+@pytest.mark.parametrize("geom", GEOMS, ids=lambda g: g[0])
+def test_panel_segments_kill_at_panel_end(geom):
+    """A runtime kill found at a panel-end boundary: the REBUILD log is the
+    scheduled driver's for the same kill, and R is still exact."""
+    _, P, m_loc, n, b = geom
+    comm = SimComm(P)
+    A = _matrix(P, m_loc, n)
+    geometry = sweep_geometry(P, m_loc, n, b)
+    point = sweep_point(1, "trailing", geometry.levels - 1)
+    orch = SweepOrchestrator(
+        A, comm, b, segment_points=panel_points(geometry),
+        fault_hooks=[ScriptedKiller({point: [2]})])
+    got = orch.run()
+    _assert_r_matches_float64(got.R, A)
+    sched = ft_caqr_sweep(A, comm, b,
+                          schedule=FailureSchedule(events={point: [2]}))
+    _assert_same_events(got, sched)
+    assert [(e.point, e.lane) for e in got.events] == [(point, 2)]
+    assert orch.segments_run == geometry.n_panels
+
+
+def test_panel_segments_resume_mid_panel():
+    """A state resumed inside panel 0's butterfly ladder runs to the end in
+    panel-sized segments."""
+    _, P, m_loc, n, b = GEOMS[0]
+    comm = SimComm(P)
+    A = _matrix(P, m_loc, n, seed=11)
+    s = initial_sweep_state(comm, A, b)
+    for _ in range(2):
+        s = sweep_step(comm, s)
+    assert s.cursor == sweep_point(0, "tsqr", 1)
+    got = SweepOrchestrator.from_state(
+        s, comm, segment_points=panel_points(s.geom)).run()
+    _assert_r_matches_float64(got.R, A)
+    assert got.events == []
+
+
 def test_abort_semantics_raises(ragged_reference):
     A, _ = ragged_reference
     point = sweep_point(0, "tsqr", 0)
@@ -543,7 +619,7 @@ def test_online_kill_matrix_aligned_exhaustive(lane):
     P, m_loc, n, b = 4, 8, 16, 4
     A = _matrix(P, m_loc, n, seed=0)
     comm = SimComm(P)
-    ref = caqr_factorize(A, comm, b, collect_bundles=True, use_scan=False)
+    ref = eager_sweep(A, comm, b)
     for point in iter_sweep_points(n // b, LEVELS):
         got = ft_caqr_sweep_online(
             A, comm, b, fault_hooks=[ScriptedKiller({point: [lane]})])

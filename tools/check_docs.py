@@ -4,7 +4,7 @@
 Extracts backtick-quoted references of two kinds and resolves each against
 the working tree:
 
-* file paths (``src/repro/core/caqr.py``, ``benchmarks/run.py``,
+* file paths (``src/repro/core/caqr.py``, ``bench/run_cell.py``,
   ``core/trailing.py`` — relative forms resolve by suffix anywhere under
   the repo);
 * dotted module names (``repro.ft.driver`` -> ``src/repro/ft/driver.py``

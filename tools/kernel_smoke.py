@@ -36,7 +36,7 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import autotune, backend, fused_sweep, ops, ref
+    from repro.kernels import autotune, backend, ops, ref
 
     print(f"backend fingerprint: {backend.backend_fingerprint()}")
     report = backend.engine_report()
@@ -69,7 +69,6 @@ def main() -> int:
             R2 = jnp.asarray(np.linalg.qr(rng.standard_normal((m, b)))[1], dt)
             Ct = jnp.asarray(rng.standard_normal((b, n)), dt)
             Cb = jnp.asarray(rng.standard_normal((b, n)), dt)
-            W = jnp.asarray(rng.standard_normal((m, b + 8)), dt)
             pairs = [
                 ("panel_qr", lambda: ops.panel_qr(A, 0),
                  lambda: ref.panel_qr(A, 0)),
@@ -79,8 +78,6 @@ def main() -> int:
                  lambda: ref.wy_apply(Y, T, C)),
                 ("stacked_apply", lambda: ops.stacked_apply(T, T, Ct, Cb),
                  lambda: ref.stacked_apply(T, T, Ct, Cb)),
-                ("fused_sweep", lambda: ops.panel_qr_apply(W, 0, b),
-                 lambda: fused_sweep.panel_qr_apply_ref(W, 0, b)),
             ]
             for op, k_fn, r_fn in pairs:
                 mode = backend.kernel_mode(op)
