@@ -113,9 +113,10 @@ class RecoveryEvent:
     (artifact name -> the one surviving lane it was fetched from).
 
     ``elapsed_s`` is wall-clock REBUILD latency, synced on both sides, under
-    the online orchestrator (SimComm or shard_map heal) and the eager
-    SimComm driver; under the scheduled shard_map path the whole sweep is
-    one traced program, so there it records trace time only.
+    the online orchestrator (one heal program on either comm, its time
+    split evenly among the lanes it heals) and the eager SimComm driver;
+    under the scheduled shard_map path the whole sweep is one traced
+    program, so there it records trace time only.
 
     ``xchip_bytes`` is the bytes this heal moved from one chip to another,
     counted where the heal issues each transfer (the tally of the heal
@@ -244,6 +245,62 @@ def recover_lanes(
                 f"found no live source: {e}") from None
         raise
     return state, events
+
+
+class HealProgram:
+    """A REBUILD compiled as one program, called like ``recover_lanes``
+    (``(state, newly, point, dead, sync=, on_recovered=, scheme=)``): the
+    online orchestrator's heal on either comm (``SimHeal`` in
+    ``repro.ft.online.orchestrator``, ``MeshHeal`` in
+    ``repro.launch.spmd_qr``).
+
+    A subclass traces ``recover_lanes`` of ``newly`` at one point into its
+    program; ``_run(state)`` runs it and returns the healed state with the
+    read ledger (lane -> reads) recorded while tracing. Here the events are
+    built on the host: the program's time, synced on both sides, split
+    evenly among the lanes it heals, and its cross-chip bytes on the first
+    event, so a heal's events sum to the program's own numbers.
+    ``on_recovered`` runs for each lane after the program.
+    ``program_status(state)`` says whether a call on ``state`` builds the
+    program (``"built"``) or reuses it (``"cached"``)."""
+
+    chips = "0"        # the dead lanes' chips, as ``ftqr.heal`` names them
+    xchip_reads = 0    # artifacts read from a lane on another chip
+    xchip_bytes = 0    # bytes the program moves between chips
+
+    def __init__(self, newly):
+        self.newly = sorted(newly)
+        self._ran = set()
+
+    def _run(self, state: SweepState):
+        raise NotImplementedError
+
+    def program_status(self, state: SweepState) -> str:
+        ran = jax.tree_util.tree_structure(state) in self._ran
+        return "cached" if ran else "built"
+
+    def __call__(self, state, newly, point, dead, sync=None,
+                 on_recovered=None, scheme=None):
+        del dead, scheme  # traced into the program
+        assert sorted(newly) == self.newly
+        if sync is not None:
+            sync(state)
+        t0 = time.perf_counter()
+        treedef = jax.tree_util.tree_structure(state)
+        state, reads = self._run(state)
+        self._ran.add(treedef)
+        if sync is not None:
+            sync(state)
+        elapsed = (time.perf_counter() - t0) / len(self.newly)
+        events = []
+        for i, lane in enumerate(self.newly):
+            if on_recovered is not None:
+                on_recovered(lane)
+            events.append(RecoveryEvent(
+                point=point, lane=lane, reads=dict(reads[lane]),
+                elapsed_s=elapsed,
+                xchip_bytes=self.xchip_bytes if i == 0 else 0))
+        return state, events
 
 
 def rebuild_state(

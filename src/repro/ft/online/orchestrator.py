@@ -33,12 +33,15 @@ segment where the dead lane is not the panel's deposit root). The
 one-segment-late case is regression-tested; longer blindness can
 contaminate survivors and then honestly fails the NaN oracle.
 
-Execution backends: under ``SimComm`` segments are jitted directly; for the
-production SPMD path pass ``step_fn=`` a shard_map segment runner
+Execution backends: under ``SimComm`` segments are jitted directly and the
+REBUILD runs as one compiled program per (lanes, point), ``SimHeal``, kept
+process-wide beside the segments (``compiled_heal``); for the production
+SPMD path pass ``step_fn=`` a shard_map segment runner
 (``repro.launch.spmd_qr.make_spmd_sweep_step``) — the state then lives as
 global lane-sharded arrays between segments, host-side death masking runs
 through the SimComm primitives on the identical global layout, and the
-REBUILD runs as the runner's own shard_map heal program (``step_fn.heal``).
+REBUILD runs as the runner's own shard_map heal program (``step_fn.heal``,
+``MeshHeal``). Both heal programs trace the same ``recover_lanes``.
 """
 from __future__ import annotations
 
@@ -55,6 +58,7 @@ from repro.core.comm import SimComm
 from repro.ft.coding import CodingScheme, XORPairScheme
 from repro.ft.driver import (
     FTSweepResult,
+    HealProgram,
     RecoveryEvent,
     obliterate_state,
     recover_lanes,
@@ -77,9 +81,10 @@ from repro.ft.stragglers import (
     StragglerPolicy,
 )
 
-# One jitted segment runner per (comm, segment size); jax's own cache then
-# specializes per state treedef (= per cursor), so every orchestrator over
-# the same geometry shares compiled segments.
+# One jitted segment runner per (comm, segment size), and one heal program
+# per (comm, dead lanes, point, scheme); jax's own cache then specializes
+# per state treedef (= per cursor), so every orchestrator over the same
+# geometry shares compiled segments and heals.
 _SEGMENT_CACHE: Dict[Tuple, Callable] = {}
 
 FaultHook = Callable[[object, SweepState], SweepState]
@@ -92,8 +97,9 @@ BoundaryHook = Callable[["SweepOrchestrator"], None]
 # splits the device's idle time among them (``idle_share.dispatch``,
 # ``.poll``, ``.loop``, ``.heal``, ``heal_ops``). ``sweep`` ids tie the
 # spans to their factorization; ``ftqr.sweep`` names the layout (``chips``,
-# ``lanes_per_chip``), ``ftqr.heal`` the dead lanes' ``chip`` and its
-# ``xchip_reads``, the artifacts it read from another chip.
+# ``lanes_per_chip``), ``ftqr.heal`` the dead lanes' ``chip``, its
+# ``xchip_reads``, the artifacts it read from another chip, and whether its
+# ``program`` was ``built`` (traced and compiled) or ``cached``.
 _SWEEP_IDS = itertools.count()
 
 
@@ -108,7 +114,11 @@ def forwarding_jit(f: Callable):
     only the leaves it computed; the leaves it returns untouched (the same
     tracer object as an input leaf) are re-inserted on the host. The map
     from output to input is recorded while tracing, once per input
-    structure. ``_cache_size()`` counts the compiled specializations."""
+    structure. ``_cache_size()`` counts the compiled specializations, and
+    ``lower(tree)`` lowers the program for ``tree`` (abstract leaves do).
+    Neither holds a bound method of the jitted function: that would keep
+    the function, and the owner its traced code refers to, alive for
+    good."""
     plans: Dict = {}
 
     @functools.partial(jax.jit, static_argnums=1)
@@ -127,7 +137,8 @@ def forwarding_jit(f: Callable):
         return jax.tree_util.tree_unflatten(
             out_tree, [next(fresh) if i is None else leaves[i] for i in src])
 
-    call._cache_size = run._cache_size
+    call._cache_size = lambda: run._cache_size()
+    call.lower = lambda tree: run.lower(*jax.tree_util.tree_flatten(tree))
     return call
 
 
@@ -160,6 +171,62 @@ def compiled_finalize(comm) -> Callable[[SweepState], Tuple]:
     if fn is None:
         fn = jax.jit(lambda s: finalize(comm, s))
         _SEGMENT_CACHE[key] = fn
+    return fn
+
+
+class SimHeal(HealProgram):
+    """The REBUILD of ``newly`` at ``point`` on ``SimComm`` as one compiled
+    program (``HealProgram``): ``recover_lanes`` — the strike and the
+    replay — traced whole through ``forwarding_jit``, so the leaves it hands
+    back untouched (``A0``) stay the caller's own arrays. ``recover_lanes``
+    is looked up in this module when the program is traced, and the read
+    ledger is recorded then, per state structure. An unrecoverable death
+    raises ``UnrecoverableFailure`` while tracing, before any device
+    computes."""
+
+    def __init__(self, comm, newly, point, scheme: CodingScheme):
+        super().__init__(newly)
+        lanes, ledgers = self.newly, {}
+
+        def fn(state):
+            left = set(lanes)
+            healed, events = recover_lanes(
+                comm, state, lanes, point, left,
+                on_recovered=left.discard, scheme=scheme)
+            ledgers[jax.tree_util.tree_structure(state)] = {
+                e.lane: dict(e.reads) for e in events}
+            return healed
+
+        self.ledgers = ledgers
+        self._call = forwarding_jit(fn)
+        self._cache_size = self._call._cache_size
+
+    def _run(self, state):
+        treedef = jax.tree_util.tree_structure(state)
+        state = self._call(state)
+        return state, self.ledgers[treedef]
+
+
+# Heal programs kept process-wide. Each (lanes, point) healed compiles its
+# own, and a compiled program holds device code (on the CPU, ~150 memory
+# mappings each): past this many the least recently used one is dropped.
+_HEAL_PROGRAMS = 32
+
+
+def compiled_heal(comm, newly, point, scheme: CodingScheme) -> SimHeal:
+    """The process-wide ``SimHeal`` of ``newly`` at ``point`` under
+    ``scheme``, per ``(comm kind, P)``: every orchestrator — one per
+    factorization — reuses the program its first heal there built. jax
+    specializes it per state treedef (= per geometry)."""
+    key = (type(comm).__name__, comm.axis_size(), "heal",
+           tuple(sorted(newly)), tuple(point), scheme.name, scheme.f)
+    fn = _SEGMENT_CACHE.pop(key, None)
+    if fn is None:
+        heals = [k for k in _SEGMENT_CACHE if k[2:3] == ("heal",)]
+        for old in heals[:max(0, len(heals) + 1 - _HEAL_PROGRAMS)]:
+            del _SEGMENT_CACHE[old]
+        fn = SimHeal(comm, newly, point, scheme)
+    _SEGMENT_CACHE[key] = fn  # re-inserted last: the most recently used
     return fn
 
 
@@ -630,23 +697,24 @@ class SweepOrchestrator:
     def _heal(self, newly: List[int], point) -> None:
         panel, phase, level = point
         dead = set(newly)
+        # The heal is one compiled program of ``recover_lanes`` on either
+        # comm. On SimComm it is the process-wide ``SimHeal``: the strike
+        # and the replay run as the segments run, compiled, instead of as
+        # thousands of eager device programs dispatched op by op. On the
+        # shard_map path the state lives as lane-sharded global arrays and
+        # the heal is the runner's ``MeshHeal``: ``recover_lanes`` under the
+        # segments' own comm, on the lanes' own devices, so the replay runs
+        # the sweep's floating-point program on the dead lane's chip block
+        # as the segments ran it, and each buddy artifact moves alone,
+        # point to point. Either way the healed R is bit-identical to the
+        # failure-free one; eager replay math on the global arrays would
+        # compile auto-sharded executables whose reduction order drifts by
+        # ~1 ulp, and gathering the state onto one device for the heal
+        # needs the whole state in one chip's memory.
         if self.step_fn is None:
-            heal = functools.partial(recover_lanes, self.comm)
-            chips, xchip_reads = "0", 0
+            heal = compiled_heal(self.comm, newly, point, self.scheme)
         else:
-            # On the shard_map path the state lives as lane-sharded global
-            # arrays. The heal is one program on the lanes' own devices
-            # (``MeshHeal``): ``recover_lanes`` under the segments' own
-            # comm, so the replay runs the sweep's floating-point program,
-            # on the dead lane's chip block as the segments ran it, and
-            # each buddy artifact moves alone, point to point. That keeps
-            # the healed R bit-identical to the failure-free one. Eager
-            # replay math on the global arrays would compile auto-sharded
-            # executables whose reduction order drifts by ~1 ulp, and
-            # gathering the state onto one device for the heal needs the
-            # whole state in one chip's memory.
             heal = self.step_fn.heal(self.state, newly, point, self.scheme)
-            chips, xchip_reads = heal.chips, heal.xchip_reads
 
         def on_recovered(lane: int) -> None:
             dead.discard(lane)
@@ -660,7 +728,8 @@ class SweepOrchestrator:
         with TraceAnnotation("ftqr.heal",
                              lanes=" ".join(str(l) for l in sorted(newly)),
                              panel=panel, phase=phase, level=level,
-                             chip=chips, xchip_reads=xchip_reads):
+                             chip=heal.chips, xchip_reads=heal.xchip_reads,
+                             program=heal.program_status(self.state)):
             # the SAME strike-then-rebuild protocol as the scheduled driver's
             # checkpoint — shared code, so the scheduled-vs-online bitwise
             # equivalence cannot drift apart in one copy

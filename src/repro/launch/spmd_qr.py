@@ -37,7 +37,6 @@ one ``MeshHeal`` program on the lanes' own devices.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
@@ -48,7 +47,7 @@ from repro.core.caqr import PanelFactors
 from repro.core.comm import AxisComm, MeshComm, SimComm
 from repro.core.trailing import RecoveryBundle
 from repro.dist import compat
-from repro.ft.driver import (FTSweepDriver, FTSweepResult, RecoveryEvent,
+from repro.ft.driver import (FTSweepDriver, FTSweepResult, HealProgram,
                              recover_lanes)
 from repro.ft.failures import FailureSchedule
 from repro.ft.online.state import state_lane_axes, sweep_step
@@ -268,10 +267,10 @@ def _mesh_program(mesh, axis_name: str, lanes_per_chip: int, fn, state):
     return call, program, probe
 
 
-class MeshHeal:
+class MeshHeal(HealProgram):
     """One REBUILD as a shard_map program under ``_lane_comm``, called like
-    ``repro.ft.driver.recover_lanes`` (``(state, newly, point, dead, sync=,
-    on_recovered=, scheme=)``): the orchestrator's heal on the SPMD path.
+    ``repro.ft.driver.recover_lanes`` (``HealProgram``): the orchestrator's
+    heal on the SPMD path.
 
     The program runs ``recover_lanes`` on each chip's own lane block: the
     respawned lane's replay runs on its chip, over the chip's lanes as the
@@ -283,7 +282,7 @@ class MeshHeal:
 
     def __init__(self, mesh, axis_name: str, lanes_per_chip: int, state,
                  newly, point, dead, scheme):
-        self.newly = sorted(newly)
+        super().__init__(newly)
         self.L = lanes_per_chip
         ledger = {}
 
@@ -312,29 +311,8 @@ class MeshHeal:
                    for lane, reads in self.reads.items()
                    for src in reads.values())
 
-    def __call__(self, state, newly, point, dead, sync=None,
-                 on_recovered=None, scheme=None):
-        del dead, scheme  # traced into the program
-        assert sorted(newly) == self.newly
-        if sync is not None:
-            sync(state)
-        t0 = time.perf_counter()
-        state = self._call(state)
-        if sync is not None:
-            sync(state)
-        # one program heals every newly dead lane: its time is split evenly
-        # among their events, its cross-chip bytes go on the first, so a
-        # factorization's events sum to the heal's own numbers
-        elapsed = (time.perf_counter() - t0) / len(self.newly)
-        events = []
-        for i, lane in enumerate(self.newly):
-            if on_recovered is not None:
-                on_recovered(lane)
-            events.append(RecoveryEvent(
-                point=point, lane=lane, reads=self.reads[lane],
-                elapsed_s=elapsed,
-                xchip_bytes=self.xchip_bytes if i == 0 else 0))
-        return state, events
+    def _run(self, state):
+        return self._call(state), self.reads
 
 
 def make_spmd_sweep_step(mesh=None, axis_name: str = "qr",

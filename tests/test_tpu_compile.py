@@ -155,6 +155,45 @@ def test_sweep_segment_compiles(one_chip, monkeypatch):
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB"
 
 
+def test_tall_heal_compiles(one_chip, monkeypatch):
+    """The tsqr_tall.kill1 cell's heal as the orchestrator compiles it on a
+    TPU (``SimHeal``): lane 3 of 16 SimComm lanes of 15744 x 1024 rebuilt
+    after panel 4's first trailing level, from lanes 2, 1, 7 and 11, its
+    replay in the Pallas kernels. The source matrix is handed back, not
+    copied, and the program fits the chip's HBM."""
+    from repro.core.comm import SimComm
+    from repro.ft.coding import XORPairScheme
+    from repro.ft.failures import sweep_point
+    from repro.ft.online import orchestrator
+    from repro.ft.online.state import initial_sweep_state, run_steps
+    from repro.kernels import backend
+
+    monkeypatch.setattr(backend, "platform", lambda: "tpu")
+    monkeypatch.setattr(orchestrator, "_SEGMENT_CACHE", {})
+    comm = SimComm(LANES_CELL)
+    point = sweep_point(4, "trailing", 0)
+    state = jax.eval_shape(
+        lambda a: initial_sweep_state(comm, a, B),
+        jax.ShapeDtypeStruct((LANES_CELL, 250000 // LANES_CELL, 1000),
+                             jnp.float32))
+    while state.cursor != sweep_point(4, "trailing", 1):
+        state = jax.eval_shape(lambda s: run_steps(comm, s, 1), state)
+    state = jax.tree_util.tree_map(
+        lambda x: _shape(one_chip, x.shape, x.dtype), state)
+    heal = orchestrator.compiled_heal(comm, [3], point, XORPairScheme())
+    compiled = heal._call.lower(state).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    (reads,) = heal.ledgers.values()
+    assert sorted(set(reads[3].values())) == [1, 2, 7, 11]
+    mem = compiled.memory_analysis()
+    a0 = state.A0.size * state.A0.dtype.itemsize
+    total = sum(x.size * x.dtype.itemsize
+                for x in jax.tree_util.tree_leaves(state))
+    assert mem.output_size_in_bytes < total - a0 + 2**20
+    assert _device_bytes(compiled) < HBM_BYTES, (
+        f"{_device_bytes(compiled) / 2**30:.2f} GiB")
+
+
 @pytest.fixture(scope="module")
 def mesh4(one_chip):
     """The described 2x2 host as a 1-D mesh of its four chips, and the
